@@ -3,29 +3,27 @@ at 1M synthetic docs — the perf trajectory anchor for the distributed index.
 
 Writes ``BENCH_retrieval.json`` and returns rows for the harness CSV.
 
-Run as its own entry point (``python -m benchmarks.retrieval_bench``): the
-sharded rows need a multi-device topology, and forcing it inside the main
-harness process would silently re-baseline every other table's timings —
-``benchmarks.run`` therefore shells out to this module.
+``benchmarks.run`` calls ``run`` in its own process, over whatever devices
+that process has (one process per chip: a child started after the parent
+touched JAX could not get the TPU).  Run as its own entry point
+(``python -m benchmarks.retrieval_bench``) on a CPU host, it first forces
+8 virtual host devices so the sharded rows have a mesh to shard over.
 """
 
 from __future__ import annotations
 
-from repro.launch.hostdevices import ensure_host_devices
+import json
+import time
 
-ensure_host_devices(8)
-
-import json  # noqa: E402
-import time  # noqa: E402
-
-import jax         # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np       # noqa: E402
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from benchmarks.common import timed
 from repro.core import embedding as emb
 from repro.core.metric_index import chunked_nn, exact_nn
 from repro.dist import retrieval as dr
+from repro.launch.hostdevices import ensure_host_devices
 
 N_DOCS = 1 << 20
 DIM = 64
@@ -75,4 +73,5 @@ def run(out_path: str = "BENCH_retrieval.json") -> dict:
 
 
 if __name__ == "__main__":
+    ensure_host_devices(8)
     print(json.dumps(run()))

@@ -3,15 +3,13 @@
 Prints ``name,us_per_call,derived`` CSV per the harness contract, where
 ``derived`` carries each table's headline quality/efficiency number.
 
-The sharded-retrieval rows need a multi-device topology; they run in a
-subprocess (``benchmarks.retrieval_bench``) so this process's
-single-device timing baseline for tables 1-3 and the kernel rows stays
-comparable across PRs.
+Every phase runs in this one process, over ``jax.devices()``: a chip
+belongs to one process, so a child started after this one touched JAX
+could not reach it.  A failing phase fails the run.
 """
 
 from __future__ import annotations
 
-import os
 import sys
 import time
 
@@ -22,6 +20,8 @@ def _csv(name, us, derived):
 
 def main() -> None:
     from benchmarks import common as C
+    from repro import compile_cache
+    compile_cache.enable()
 
     t0 = time.perf_counter()
     world = C.make_world(C.DEFAULT_WORLD)
@@ -72,25 +72,15 @@ def main() -> None:
         _csv(f"kernel_{name}", 1e6 * t, f"ms={1e3 * t:.3f}")
 
     # --- distributed retrieval: exact vs chunked vs sharded @ 1M docs -------
-    # own subprocess: it forces an 8-device topology, which must not leak
-    # into this process's timings
-    import json
-    import subprocess
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.retrieval_bench"],
-        capture_output=True, text=True,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    if proc.returncode:
-        print(f"retrieval bench failed:\n{proc.stderr[-2000:]}",
-              file=sys.stderr)
-    else:
-        rec = json.loads(proc.stdout.strip().splitlines()[-1])
-        for name in ("exact", "chunked", "sharded"):
-            _csv(f"retrieval_{name}_1M", rec[f"{name}_us"],
-                 f"ndev={rec['n_devices']};"
-                 f"identical={rec['rankings_identical']}")
-        _csv("retrieval_sharded_speedup", rec["sharded_us"],
-             f"vs_chunked={rec['sharded_speedup_vs_chunked']:.2f}x")
+    # in this process, sharded over this process's devices
+    from benchmarks import retrieval_bench
+    rec = retrieval_bench.run()
+    for name in ("exact", "chunked", "sharded"):
+        _csv(f"retrieval_{name}_1M", rec[f"{name}_us"],
+             f"ndev={rec['n_devices']};"
+             f"identical={rec['rankings_identical']}")
+    _csv("retrieval_sharded_speedup", rec["sharded_us"],
+         f"vs_chunked={rec['sharded_speedup_vs_chunked']:.2f}x")
 
     # --- batched multi-session serving (sequential loop vs waves) -----------
     from benchmarks import serve_bench

@@ -885,6 +885,8 @@ def main():
                          "default follows REPRO_CORPUS_DTYPE)")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     if args.chaos:
         run_chaos(smoke=args.smoke, dtype=args.dtype, out_path=args.out)
     elif args.open_loop:

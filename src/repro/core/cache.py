@@ -68,6 +68,13 @@ __all__ = ["CacheState", "CacheConfig", "ProbeResult", "init_cache",
            "validate_state"]
 
 
+@jax.jit
+def _scatter_rows(state: CacheState, idx: jax.Array,
+                  sub: CacheState) -> CacheState:
+    return jax.tree_util.tree_map(
+        lambda full, part: full.at[idx].set(part, mode="drop"), state, sub)
+
+
 class MetricCache:
     """Stateful host wrapper over the functional cache ops."""
 
@@ -171,10 +178,11 @@ class BatchedMetricCache:
         return jax.tree_util.tree_map(lambda x: x[idx], self.state)
 
     def scatter(self, sessions, sub: CacheState):
-        """Write a wave's updated sub-state back into the stacked state."""
-        idx = jnp.asarray(sessions)
-        self.state = jax.tree_util.tree_map(
-            lambda full, part: full.at[idx].set(part), self.state, sub)
+        """Write a wave's updated sub-state back into the stacked state:
+        row j of ``sub`` lands in session ``sessions[j]``; an entry equal
+        to ``n_sessions`` drops its row (padded or failed wave rows), so
+        the caller never gathers a trimmed copy of ``sub`` first."""
+        self.state = _scatter_rows(self.state, jnp.asarray(sessions), sub)
 
     def probe(self, psi, epsilon=None, backend=None) -> ProbeResult:
         eps = self.cfg.epsilon if epsilon is None else epsilon

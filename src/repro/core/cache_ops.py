@@ -231,9 +231,15 @@ def query(state: CacheState, psi: jax.Array, k: int):
 def dedup_mask(new_ids: jax.Array, existing_ids: jax.Array) -> jax.Array:
     """True for the first occurrence of each id not already cached."""
     in_cache = (new_ids[:, None] == existing_ids[None, :]).any(axis=1)
+    # dup_later[j]: some i < j carries the same id — a (kc, kc) compare
+    # under a strict lower-triangle mask (an index-pair scatter would embed
+    # O(kc^2) constant indices in the program and compile for tens of
+    # seconds at k_c = 1000)
     kc = new_ids.shape[0]
-    ii, jj = jnp.triu_indices(kc, k=1)  # j > i pairs
-    dup_later = jnp.zeros((kc,), bool).at[jj].max(new_ids[jj] == new_ids[ii])
+    earlier = (jax.lax.broadcasted_iota(jnp.int32, (kc, kc), 0)
+               < jax.lax.broadcasted_iota(jnp.int32, (kc, kc), 1))
+    same = new_ids[:, None] == new_ids[None, :]
+    dup_later = jnp.logical_and(same, earlier).any(axis=0)
     return jnp.logical_and(~in_cache, ~dup_later)
 
 

@@ -97,7 +97,8 @@ def streaming_topk(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array,
                 preferred_element_type=jnp.int32)                # (q, chunk)
             scores = acc.astype(jnp.float32) * q_scale_col
         else:
-            scores = queries @ cd.astype(jnp.float32).T          # (q, chunk)
+            scores = jnp.matmul(queries, cd.astype(jnp.float32).T,
+                                precision=jax.lax.Precision.HIGHEST)
         scores = quant.scale_scores(scores, cs)
         if masked:
             scores = jnp.where(ci[None, :] < 0, -jnp.inf, scores)

@@ -261,8 +261,7 @@ class SharedTier:
 
         Answers bound for distinct shards batch into one
         ``insert_batched`` launch; same-shard answers split into ordered
-        sub-waves (two inserts into one gathered row copy would lose one
-        of them at scatter).  Claim ring slots are wave-stamped for TTL.
+        launches.  Claim ring slots are wave-stamped for TTL.
         """
         pending, self._pending = self._pending, []
         promoted = 0
@@ -272,23 +271,49 @@ class SharedTier:
             for p in pending:
                 (now if p[0] not in seen else later).append(p)
                 seen.add(p[0])
-            shards = np.array([p[0] for p in now], np.int32)
-            psi = jnp.asarray(np.stack([p[1] for p in now]))
-            radius = jnp.asarray(np.array([p[2] for p in now], np.float32))
-            emb = jnp.asarray(np.stack([p[3] for p in now]))
-            ids = jnp.asarray(np.stack([p[4] for p in now]))
-            sub = self._gather(shards)
-            slots = np.asarray(sub.n_queries) % self.cfg.max_queries
-            sub, dropped = insert_batched(sub, self.cfg, psi, radius, emb,
-                                          ids, backend=backend or self.backend)
-            self._scatter(shards, sub)
-            self._claim_wave[shards, slots] = self.wave
-            self._claim_alive[shards, slots] = True
-            self.total_dropped += int(np.asarray(dropped).sum())
+            self._insert(now, len(now[0][4]), backend)
             promoted += len(now)
             pending = later
         self.n_promoted += promoted
         return promoted
+
+    def warm(self, *, k: int, k_c: int, rows, backend: Optional[str] = None
+             ) -> None:
+        """Compile, before serving, the programs a wave runs here only once
+        the tier holds claims: the top-``k`` answer query at each wave size
+        in ``rows`` and the admission flush of ``k_c``-doc answers.  The
+        tier's state is left as it was."""
+        state = self.state
+        for b in rows:
+            psi = np.zeros((b, self.cfg.dim), np.float32)
+            self.query_rows(psi, self.route(psi), k, backend=backend)
+        self._insert([], k_c, backend)
+        self.state = state
+
+    def _insert(self, answers: list, k_c: int,
+                backend: Optional[str]) -> None:
+        """One ``insert_batched`` launch over the whole stacked state, one
+        row per shard.  ``answers`` holds at most one pending answer per
+        shard; every other row passes ``do=False`` and keeps its state
+        bit-identically, so each flush runs the same program whatever the
+        number of admitted answers."""
+        n, dim = self.n_shards, self.cfg.dim
+        psi = np.zeros((n, dim), np.float32)
+        radius = np.zeros((n,), np.float32)
+        emb = np.zeros((n, k_c, dim), np.float32)
+        ids = np.full((n, k_c), -1, np.int32)
+        do = np.zeros((n,), bool)
+        for shard, p, r, e, i in answers:
+            psi[shard], radius[shard], emb[shard], ids[shard] = p, r, e, i
+            do[shard] = True
+        slots = np.asarray(self.state.n_queries) % self.cfg.max_queries
+        self.state, dropped = insert_batched(
+            self.state, self.cfg, psi, radius, emb, ids, do=do,
+            backend=backend or self.backend)
+        shards = np.nonzero(do)[0]
+        self._claim_wave[shards, slots[shards]] = self.wave
+        self._claim_alive[shards, slots[shards]] = True
+        self.total_dropped += int(np.asarray(dropped).sum())
 
     # ------------------------------------------------------------ result memo
 

@@ -69,6 +69,9 @@ class TopicWorld:
         return self.doc_emb.shape[0]
 
 
+_BLOCK = 1 << 16      # corpus rows per generation / qrel-scan step
+
+
 def _unit(x: np.ndarray, axis=-1) -> np.ndarray:
     return x / np.linalg.norm(x, axis=axis, keepdims=True)
 
@@ -105,22 +108,25 @@ def make_world(cfg: WorldConfig = WorldConfig()) -> TopicWorld:
         return z @ frames[topic].T
 
     # --- corpus ----------------------------------------------------------
-    topic_docs = np.concatenate([
-        _unit(centers[t] + in_subspace(t, (cfg.docs_per_topic,),
-                                       cfg.doc_sigma))
-        for t in range(cfg.n_topics)])
-    bg_docs = _unit(rng.standard_normal((cfg.n_background, cfg.dim)))
-    doc_emb = np.concatenate([topic_docs, bg_docs], axis=0)
+    # written block by block into one array (the same draws, in the same
+    # order, as whole-corpus calls): a multi-million-doc world never holds
+    # a second copy of the corpus
+    n_topic_docs = cfg.n_topics * cfg.docs_per_topic
+    n_docs = n_topic_docs + cfg.n_background
+    doc_emb = np.empty((n_docs, cfg.dim))
+    for t in range(cfg.n_topics):
+        doc_emb[t * cfg.docs_per_topic:(t + 1) * cfg.docs_per_topic] = _unit(
+            centers[t] + in_subspace(t, (cfg.docs_per_topic,), cfg.doc_sigma))
+    for lo in range(n_topic_docs, n_docs, _BLOCK):
+        hi = min(lo + _BLOCK, n_docs)
+        doc_emb[lo:hi] = _unit(rng.standard_normal((hi - lo, cfg.dim)))
     # non-unit norms so Eq. 1's document branch is non-trivial
-    norms = 1.0 + cfg.norm_jitter * (rng.random(doc_emb.shape[0]) * 2 - 1)
-    doc_emb = doc_emb * norms[:, None]
+    norms = 1.0 + cfg.norm_jitter * (rng.random(n_docs) * 2 - 1)
+    doc_emb *= norms[:, None]
     doc_topic = np.concatenate([
         np.repeat(np.arange(cfg.n_topics), cfg.docs_per_topic),
         np.full(cfg.n_background, -1),
     ])
-
-    # normalized docs for qrel geometry (relevance ~ angular proximity)
-    doc_unit = _unit(doc_emb)
 
     # --- conversations ----------------------------------------------------
     convs: List[Conversation] = []
@@ -128,23 +134,49 @@ def make_world(cfg: WorldConfig = WorldConfig()) -> TopicWorld:
         topic = int(rng.integers(cfg.n_topics))
         point = _unit(centers[topic] +
                       in_subspace(topic, (), cfg.doc_sigma * 0.5))
-        queries, ideals, qrels = [], [], []
+        queries, ideals = [], []
         for _t in range(cfg.turns):
             if _t > 0 and rng.random() < cfg.subtopic_prob:
                 point = _unit(centers[topic] +
                               in_subspace(topic, (), cfg.subtopic_sigma))
             point = _unit(point + in_subspace(topic, (), cfg.drift_sigma))
             q = point + in_subspace(topic, (), cfg.query_sigma)
-            sims = doc_unit @ point
-            order = np.argsort(-sims)
-            qr = {int(d): 2 for d in order[:cfg.n_rel2]}
-            qr.update({int(d): 1 for d in order[cfg.n_rel2:cfg.n_rel2 + cfg.n_rel1]})
             queries.append(q)
             ideals.append(point.copy())
-            qrels.append(qr)
         convs.append(Conversation(topic=topic,
                                   queries=np.stack(queries),
                                   ideal_points=np.stack(ideals),
-                                  qrels=qrels))
+                                  qrels=[]))
+
+    # graded qrels: the docs nearest each ideal point (angular proximity),
+    # every turn of every conversation scored in one pass over the corpus
+    n_rel = cfg.n_rel2 + cfg.n_rel1
+    if convs:
+        order = _nearest(doc_emb, np.concatenate(
+            [c.ideal_points for c in convs]), n_rel)
+        for i, c in enumerate(convs):
+            for row in order[i * cfg.turns:(i + 1) * cfg.turns]:
+                qr = {int(d): 2 for d in row[:cfg.n_rel2]}
+                qr.update({int(d): 1 for d in row[cfg.n_rel2:n_rel]})
+                c.qrels.append(qr)
     return TopicWorld(cfg=cfg, doc_emb=doc_emb, doc_topic=doc_topic,
                       centers=centers, conversations=convs)
+
+
+def _nearest(doc_emb: np.ndarray, points: np.ndarray, n: int) -> np.ndarray:
+    """(P, n) ids of the docs with the highest cosine to each unit point,
+    best first — a blocked scan with a running top-n, so the normalized
+    corpus never materializes whole."""
+    best_s = np.full((points.shape[0], 0), -np.inf)
+    best_i = np.zeros((points.shape[0], 0), np.int64)
+    for lo in range(0, doc_emb.shape[0], _BLOCK):
+        sims = points @ _unit(doc_emb[lo:lo + _BLOCK]).T        # (P, B)
+        take = min(n, sims.shape[1])
+        part = np.argpartition(-sims, take - 1, axis=1)[:, :take]
+        best_s = np.concatenate(
+            [best_s, np.take_along_axis(sims, part, axis=1)], axis=1)
+        best_i = np.concatenate([best_i, part + lo], axis=1)
+        keep = np.argsort(-best_s, axis=1, kind="stable")[:, :n]
+        best_s = np.take_along_axis(best_s, keep, axis=1)
+        best_i = np.take_along_axis(best_i, keep, axis=1)
+    return best_i

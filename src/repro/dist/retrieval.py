@@ -28,10 +28,9 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import quant
+from repro.core import layout, quant
 from repro.core.metric_index import SearchResult, _as_result, scan_topk
 from repro.dist.api import active_mesh
 from repro.kernels import dispatch as kdispatch
@@ -110,6 +109,15 @@ def _pad_corpus(docs: jax.Array, doc_ids: jax.Array, rows: int,
     return docs, doc_ids, scale
 
 
+def _pad_rows(x: np.ndarray, rows: int, value) -> np.ndarray:
+    """Host-side sentinel pad of the leading axis to exactly ``rows``."""
+    pad = rows - x.shape[0]
+    if not pad:
+        return x
+    return np.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1),
+                  constant_values=value)
+
+
 def shard_corpus(docs, doc_ids, *, scale: Optional[jax.Array] = None,
                  mesh: Optional[Mesh] = None,
                  axes: Optional[Sequence[str]] = None, chunk: int = 4096):
@@ -119,13 +127,18 @@ def shard_corpus(docs, doc_ids, *, scale: Optional[jax.Array] = None,
     per-document f32 score multiplier, which shards row-aligned with it.
     Returns (docs, doc_ids, scale, mesh, chunk_eff) with the rows already
     laid out P(axes) across devices, so repeated ``sharded_nn`` calls (a
-    serving index) pay no per-query re-pad or host->mesh re-layout.
+    serving index) pay no per-query re-pad or host->mesh re-layout.  The
+    padding happens in host memory and each device receives only its own
+    slice: no device holds (or computes on) the whole corpus.
     """
     mesh, axes, n_dev = _resolve(mesh, axes)
-    docs = jnp.asarray(docs)
-    doc_ids = jnp.asarray(doc_ids, jnp.int32)
+    docs = np.asarray(docs)
     per, chunk_eff = _slice_layout(docs.shape[0], n_dev, chunk)
-    docs, doc_ids, scale = _pad_corpus(docs, doc_ids, per * n_dev, scale)
+    rows = per * n_dev
+    docs = _pad_rows(docs, rows, 0)
+    doc_ids = _pad_rows(np.asarray(doc_ids, np.int32), rows, -1)
+    if scale is not None:
+        scale = _pad_rows(np.asarray(scale, np.float32), rows, 1.0)
     entry = axes if len(axes) > 1 else axes[0]
     docs = jax.device_put(docs, NamedSharding(mesh, P(entry, None)))
     doc_ids = jax.device_put(doc_ids, NamedSharding(mesh, P(entry)))
@@ -171,9 +184,9 @@ def _sharded_search_fn(mesh: Mesh, axes: Tuple[str, ...], k: int, chunk: int,
                                     backend=backend, int8_dot=int8_dot))
         in_specs = (P(axis_entry, None), P(axis_entry), P(None, None))
 
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                   out_specs=(P(None, None), P(None, None)),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=(P(None, None), P(None, None)),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -228,6 +241,50 @@ class ShardTopK(NamedTuple):
     ids: np.ndarray        # (B, k) global doc ids, -1 past the shard's corpus
 
 
+QUANT_BLOCK = 1 << 16     # corpus rows quantized per step on the device
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",), donate_argnums=(0, 1))
+def _write_block(payload, scale, block, start, *, dtype: str):
+    """Quantize one block of raw f32 rows and write it into the device-
+    resident payload / scale at row ``start`` (buffers donated: in place).
+    Rows quantize independently, so block-wise equals whole-corpus
+    quantization; feature columns past the block's width stay zero."""
+    qc = quant.quantize(block, dtype)
+    data = jnp.pad(qc.data, ((0, 0), (0, payload.shape[1] - block.shape[1])))
+    payload = jax.lax.dynamic_update_slice(payload, data, (start, 0))
+    if scale is not None:
+        scale = jax.lax.dynamic_update_slice(scale, qc.scale, (start,))
+    return payload, scale
+
+
+def _place_slice(docs, doc_ids, rows: int, width: int, dtype: str, device):
+    """Quantize a corpus slice straight onto ``device``.
+
+    The slice streams from host memory in ``QUANT_BLOCK``-row blocks; each
+    block is quantized on ``device`` itself and written into a payload
+    pre-allocated there at (``rows``, ``width``) — rows padded with
+    sentinels (zero payload, scale 1, id -1), features zero-padded.  No
+    other device is touched and the f32 slice is never resident whole.
+    """
+    n = docs.shape[0]
+    store = quant.storage_dtype(dtype)
+    payload = jnp.zeros((rows, width), store, device=device)
+    scale = None
+    if dtype == "int8":
+        scale = jnp.ones((rows,), jnp.float32, device=device)
+    for lo in range(0, n, QUANT_BLOCK):
+        block = jax.device_put(
+            np.asarray(docs[lo:lo + QUANT_BLOCK], np.float32), device)
+        payload, scale = _write_block(payload, scale, block,
+                                      jnp.int32(lo), dtype=dtype)
+        # one block in flight: host staging stays O(block), not O(slice)
+        payload.block_until_ready()
+    ids = np.full((rows,), -1, np.int32)
+    ids[:n] = np.asarray(doc_ids, np.int32)
+    return payload, jax.device_put(ids, device), scale
+
+
 class DeviceShard:
     """A host-callable index shard pinned to one device.
 
@@ -237,56 +294,59 @@ class DeviceShard:
     shards on distinct devices in parallel.  The scan is the shared
     ``scan_topk`` contract (``backend`` pins a ``kernels.dispatch`` tier;
     ``dtype`` the corpus storage format — None follows the
-    ``REPRO_CORPUS_DTYPE`` policy, and the slice is quantized once at
-    construction).
+    ``REPRO_CORPUS_DTYPE`` policy).  The slice is quantized once, at
+    construction, on its own device (``_place_slice``) and stored at the
+    lane-aligned width the kernel tiers stream, so a search copies nothing
+    of the corpus; queries are zero-padded to that width per call, and
+    their rows to a power of two.
     """
 
     def __init__(self, docs, doc_ids, device=None, chunk: int = 4096,
                  backend: Optional[str] = None, dtype: Optional[str] = None):
-        docs = jnp.asarray(docs)
-        doc_ids = jnp.asarray(doc_ids, jnp.int32)
-        n = docs.shape[0]
+        n, dim = docs.shape
         self.chunk = int(min(chunk, max(8, n)))
         self.dtype = quant.resolve_dtype(dtype)
-        qc = quant.quantize(docs, self.dtype)
-        docs, doc_ids, scale = _pad_corpus(qc.data, doc_ids,
-                                           n + (-n) % self.chunk, qc.scale)
         self.device = device
         self.backend = kdispatch.resolve(backend)
         # the int8-MXU-dot policy is resolved once per shard, so a shard's
         # scoring rule never flips mid-deployment under an env change
         self.int8_dot = quant.resolve_int8_dot(None, self.docs_dtype())
         self.n_docs = n
-        self.docs = jax.device_put(docs, device)
-        self.doc_ids = jax.device_put(doc_ids, device)
-        self.scale = (None if scale is None
-                      else jax.device_put(scale, device))
+        self.docs, self.doc_ids, self.scale = _place_slice(
+            docs, doc_ids, n + (-n) % self.chunk, layout.phys_dim(dim),
+            self.dtype, device)
 
     def docs_dtype(self):
         return quant.storage_dtype(self.dtype)
 
     def __call__(self, queries, k: int) -> ShardTopK:
-        q = jnp.asarray(queries, jnp.float32)
+        q = np.asarray(queries, np.float32)
         if q.ndim == 1:
             q = q[None]
-        if self.device is not None:
-            q = jax.device_put(q, self.device)
-        scores, ids = scan_topk(self.docs, self.doc_ids, q, int(k),
+        b = q.shape[0]
+        # rows pad to a power of two and features to the stored width: a
+        # free-running miss count compiles at most log2(wave) scan programs
+        rows = 1 << max(b - 1, 0).bit_length()
+        q = np.pad(q, ((0, rows - b), (0, self.docs.shape[1] - q.shape[1])))
+        scores, ids = scan_topk(self.docs, self.doc_ids,
+                                jax.device_put(q, self.device), int(k),
                                 chunk=self.chunk, backend=self.backend,
                                 scale=self.scale, int8_dot=self.int8_dot)
-        return ShardTopK(np.asarray(scores), np.asarray(ids))
+        return ShardTopK(np.asarray(scores)[:b], np.asarray(ids)[:b])
 
 
 def make_device_shards(docs, doc_ids=None, *, devices=None,
                        chunk: int = 4096, dtype: Optional[str] = None) -> list:
     """Split a corpus into one ``DeviceShard`` per device (equal, padded
-    slices so every shard shares a single jit trace)."""
-    docs = jnp.asarray(docs)
-    if doc_ids is None:
-        doc_ids = jnp.arange(docs.shape[0], dtype=jnp.int32)
-    doc_ids = jnp.asarray(doc_ids, jnp.int32)
-    devices = list(devices if devices is not None else jax.devices())
+    slices so every shard shares a single jit trace).  Each slice goes
+    from host memory straight to its own device: a device-resident
+    ``docs`` is fetched to the host first, never sliced on device 0."""
+    docs = np.asarray(docs)
     n = docs.shape[0]
+    if doc_ids is None:
+        doc_ids = np.arange(n, dtype=np.int32)
+    doc_ids = np.asarray(doc_ids, np.int32)
+    devices = list(devices if devices is not None else jax.devices())
     per = -(-n // len(devices))
     shards = []
     for i, dev in enumerate(devices):

@@ -47,10 +47,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.knn.knn import HIGHEST, extract_topk
+
 NEG_INF = float("-inf")
 BIG_NEG = -1.0e38    # empty/sentinel slot key (extracted after all finite)
 INIT = -2.0e38       # carry initialization (never outranks an empty slot)
 KNOCK = -3.0e38      # already-extracted candidate
+KC_CHUNK = 128       # batch rows per insert-scatter step (lane multiple)
 
 
 def make_wave_kernel(*, tile_c: int, tiles: int, kc: int, k: int,
@@ -59,26 +62,31 @@ def make_wave_kernel(*, tile_c: int, tiles: int, kc: int, k: int,
 
     The ref operand list depends on the static flags; see
     ``repro.kernels.cache_wave.ops`` for the exact ordering (inputs,
-    then outputs, then scratch).
+    then outputs, then scratch).  Per-row vectors arrive as (1, 1, X)
+    blocks of (S, 1, X) arrays and the two scalar tables as whole (S, 8)
+    SMEM arrays indexed by the session grid axis — the block layouts the
+    TPU lowering accepts.  Every single-element update (ring slot, carry
+    column) is a full-width masked select, never a store at a dynamic
+    lane offset.
     """
 
     def kernel(*refs):
         it = iter(refs)
-        ints_ref = next(it)                       # SMEM (1, 8) int32
+        ints_ref = next(it)                       # SMEM (S, 8) int32
         demb_ref = next(it)                       # (1, TILE_C, D) payload
-        dids_ref = next(it)                       # (1, TILE_C) int32
-        dscale_ref = next(it)                     # (1, TILE_C) f32
+        dids_ref = next(it)                       # (1, 1, TILE_C) int32
+        dscale_ref = next(it)                     # (1, 1, TILE_C) f32
         if with_insert:
-            dstamp_ref = next(it)                 # (1, TILE_C) int32
-            floats_ref = next(it)                 # SMEM (1, 8) f32
+            dstamp_ref = next(it)                 # (1, 1, TILE_C) int32
+            floats_ref = next(it)                 # SMEM (S, 8) f32
             emb_ref = next(it)                    # (1, KC, D) payload
             escale_ref = next(it)                 # (1, 1, KC) f32
             nids_ref = next(it)                   # (1, 1, KC) int32
             pos_ref = next(it)                    # (1, 1, KC) int32
             psis_ref = next(it)                   # (1, 8, D) payload, row 0
             qemb_ref = next(it)                   # (1, QMAX, D) payload
-            qrad_ref = next(it)                   # (1, QMAX) radius dtype
-            qsc_ref = next(it)                    # (1, QMAX) f32
+            qrad_ref = next(it)                   # (1, 1, QMAX) radius dtype
+            qsc_ref = next(it)                    # (1, 1, QMAX) f32
         if with_query:
             psi_ref = next(it)                    # (1, 8, D) f32, row 0 live
         if with_insert:
@@ -90,65 +98,91 @@ def make_wave_kernel(*, tile_c: int, tiles: int, kc: int, k: int,
             o_qrad = next(it)
             o_qsc = next(it)
         if with_query:
-            o_vals = next(it)                     # (1, k) f32
-            o_ids = next(it)                      # (1, k) int32
-            o_slots = next(it)                    # (1, k) int32
-            carry_v = next(it)                    # VMEM (1, k) f32
-            carry_i = next(it)                    # VMEM (1, k) int32
-            carry_s = next(it)                    # VMEM (1, k) int32
+            o_vals = next(it)                     # (1, 1, Kp) f32
+            o_ids = next(it)                      # (1, 1, Kp) int32
+            o_slots = next(it)                    # (1, 1, Kp) int32
+        if with_insert:
+            scat_ref = next(it)                   # VMEM (TILE_C, D) f32
+        if with_query:
+            carry_v = next(it)                    # VMEM (1, Kp) f32
+            carry_i = next(it)                    # VMEM (1, Kp) int32
+            carry_s = next(it)                    # VMEM (1, Kp) int32
 
+        row = pl.program_id(0)
         t = pl.program_id(1)
         old_emb = demb_ref[0]                     # (TILE_C, D) payload
-        old_ids = dids_ref[...]                   # (1, TILE_C)
-        old_scale = dscale_ref[...]               # (1, TILE_C)
+        old_ids = dids_ref[0]                     # (1, TILE_C)
+        old_scale = dscale_ref[0]                 # (1, TILE_C)
 
         if with_insert:
-            rec = ints_ref[0, 1]
-            qslot = ints_ref[0, 2]
-            step_ins = ints_ref[0, 3]
-            base = t * tile_c
-            pos_c = pos_ref[0].reshape(kc, 1)     # (KC, 1)
-            col = base + jax.lax.broadcasted_iota(jnp.int32, (kc, tile_c), 1)
-            hit = pos_c == col                    # (KC, TILE_C) one-hot-ish
-            written = hit.any(axis=0, keepdims=True)          # (1, TILE_C)
-            # MXU scatter: exactly one hit per written column (positions are
-            # unique among kept docs), so the f32 matmul reproduces the row
-            # values exactly — including int8/bf16 payloads, whose values
-            # round-trip f32 without loss.
-            scat = jax.lax.dot_general(
-                hit.astype(jnp.float32), emb_ref[0].astype(jnp.float32),
-                (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)           # (TILE_C, D)
-            blended = jnp.where(written.reshape(tile_c, 1), scat,
+            chunk_n = min(kc, KC_CHUNK)       # the wrapper pads kc to it
+            rec = ints_ref[row, 1]
+            qslot = ints_ref[row, 2]
+            step_ins = ints_ref[row, 3]
+            col = t * tile_c + jax.lax.broadcasted_iota(
+                jnp.int32, (chunk_n, tile_c), 1)
+            scat_ref[...] = jnp.zeros(scat_ref.shape, jnp.float32)
+
+            def scatter_chunk(c, acc):
+                # one chunk_n-row slice of the batch per step: a loop over
+                # small one-hot blocks instead of one (KC, TILE_C) block
+                # keeps the compiled kernel (and its compile time) small
+                hits, ids_acc, sc_acc = acc
+                lo = pl.multiple_of(c * chunk_n, chunk_n)
+                chunk = pl.ds(lo, chunk_n)
+                hit = pos_ref[0, :, chunk].reshape(chunk_n, 1) == col
+                # MXU scatter: exactly one hit per written column (positions
+                # are unique among kept docs), so the full-precision f32
+                # matmul reproduces the row values exactly — including
+                # int8/bf16 payloads, whose values round-trip f32 exactly
+                scat_ref[...] += jax.lax.dot_general(
+                    hit.astype(jnp.float32),
+                    emb_ref[0, chunk, :].astype(jnp.float32),
+                    (((0,), (0,)), ((), ())), precision=HIGHEST,
+                    preferred_element_type=jnp.float32)       # (TILE_C, D)
+                ids_c = nids_ref[0, :, chunk].reshape(chunk_n, 1)
+                sc_c = escale_ref[0, :, chunk].reshape(chunk_n, 1)
+                return (
+                    hits + jnp.sum(hit.astype(jnp.int32), axis=0,
+                                   keepdims=True),
+                    ids_acc + jnp.sum(jnp.where(hit, ids_c, 0), axis=0,
+                                      keepdims=True),
+                    sc_acc + jnp.sum(jnp.where(hit, sc_c, 0.0), axis=0,
+                                     keepdims=True))
+
+            hits, scat_ids, scat_sc = jax.lax.fori_loop(
+                0, kc // chunk_n, scatter_chunk,
+                (jnp.zeros((1, tile_c), jnp.int32),
+                 jnp.zeros((1, tile_c), jnp.int32),
+                 jnp.zeros((1, tile_c), jnp.float32)))
+            written = hits > 0                                # (1, TILE_C)
+            # (1, TILE_C) -> (TILE_C, 1) through int32: Mosaic can re-lay a
+            # 32-bit row as a column, not a mask
+            written_col = hits.reshape(tile_c, 1) > 0
+            blended = jnp.where(written_col, scat_ref[...],
                                 old_emb.astype(jnp.float32))
-            ids_c = nids_ref[0].reshape(kc, 1)
-            scat_ids = jnp.sum(
-                jnp.where(hit, jnp.broadcast_to(ids_c, hit.shape), 0),
-                axis=0, keepdims=True).astype(jnp.int32)
             ids_bl = jnp.where(written, scat_ids, old_ids)
-            sc_c = escale_ref[0].reshape(kc, 1)
-            scat_sc = jnp.sum(
-                jnp.where(hit, jnp.broadcast_to(sc_c, hit.shape), 0.0),
-                axis=0, keepdims=True)
             scale_bl = jnp.where(written, scat_sc, old_scale)
             o_demb[0] = blended.astype(o_demb.dtype)
-            o_dids[...] = ids_bl
-            o_dscale[...] = scale_bl
-            o_dstamp[...] = jnp.where(written, step_ins, dstamp_ref[...])
+            o_dids[0] = ids_bl
+            o_dscale[0] = scale_bl
+            o_dstamp[0] = jnp.where(written, step_ins, dstamp_ref[0])
 
             @pl.when(t == 0)
             def _ring():                          # (psi, r_a) record ring
-                o_qemb[0] = qemb_ref[0]
-                o_qrad[...] = qrad_ref[...]
-                o_qsc[...] = qsc_ref[...]
-
-                @pl.when(rec == 1)
-                def _write_slot():
-                    o_qemb[0, pl.ds(qslot, 1), :] = psis_ref[0, :1, :]
-                    o_qrad[0, pl.ds(qslot, 1)] = jnp.full(
-                        (1,), floats_ref[0, 0], o_qrad.dtype)
-                    o_qsc[0, pl.ds(qslot, 1)] = jnp.full(
-                        (1,), floats_ref[0, 1], jnp.float32)
+                slot_rows = jax.lax.broadcasted_iota(
+                    jnp.int32, qemb_ref.shape[1:], 0) == qslot
+                slot_lanes = jax.lax.broadcasted_iota(
+                    jnp.int32, qrad_ref.shape[1:], 1) == qslot
+                put_row = jnp.logical_and(slot_rows, rec == 1)
+                put_lane = jnp.logical_and(slot_lanes, rec == 1)
+                o_qemb[0] = jnp.where(put_row, psis_ref[0, :1, :],
+                                      qemb_ref[0])
+                o_qrad[0] = jnp.where(put_lane,
+                                      floats_ref[row, 0].astype(o_qrad.dtype),
+                                      qrad_ref[0])
+                o_qsc[0] = jnp.where(put_lane, floats_ref[row, 1],
+                                     qsc_ref[0])
         else:
             blended = old_emb.astype(jnp.float32)
             ids_bl = old_ids
@@ -164,6 +198,7 @@ def make_wave_kernel(*, tile_c: int, tiles: int, kc: int, k: int,
             psi_row = psi_ref[0, :1, :]                        # (1, D)
             scores = jax.lax.dot_general(
                 psi_row, blended, (((1,), (1,)), ((), ())),
+                precision=HIGHEST,
                 preferred_element_type=jnp.float32)            # (1, TILE_C)
             scores = scores * scale_bl
             key = jnp.where(ids_bl < 0, BIG_NEG, scores)
@@ -173,29 +208,16 @@ def make_wave_kernel(*, tile_c: int, tiles: int, kc: int, k: int,
             cand_v = jnp.concatenate([carry_v[...], key], axis=1)
             cand_i = jnp.concatenate([carry_i[...], ids_bl], axis=1)
             cand_s = jnp.concatenate([carry_s[...], slot_row], axis=1)
-            col2 = jax.lax.broadcasted_iota(jnp.int32, cand_v.shape, 1)
-
-            def extract(j, s):
-                m = jnp.max(s, axis=1)
-                a = jnp.argmax(s, axis=1).astype(jnp.int32)
-                hitc = col2 == a[:, None]
-                pid = jnp.sum(jnp.where(hitc, cand_i, 0),
-                              axis=1).astype(jnp.int32)
-                pslot = jnp.sum(jnp.where(hitc, cand_s, 0),
-                                axis=1).astype(jnp.int32)
-                carry_v[:, pl.dslice(j, 1)] = m[:, None]
-                carry_i[:, pl.dslice(j, 1)] = pid[:, None]
-                carry_s[:, pl.dslice(j, 1)] = pslot[:, None]
-                return jnp.where(hitc, KNOCK, s)
-
-            jax.lax.fori_loop(0, k, extract, cand_v)
+            carry_v[...], (carry_i[...], carry_s[...]) = extract_topk(
+                cand_v, (cand_i, cand_s), carry_v[...],
+                (carry_i[...], carry_s[...]), k=k, knock=KNOCK)
 
             @pl.when(t == tiles - 1)
             def _emit():
                 v = carry_v[...]
                 live = v > BIG_NEG
-                o_vals[...] = jnp.where(live, v, NEG_INF)
-                o_ids[...] = jnp.where(live, carry_i[...], -1)
-                o_slots[...] = carry_s[...]
+                o_vals[0] = jnp.where(live, v, NEG_INF)
+                o_ids[0] = jnp.where(live, carry_i[...], -1)
+                o_slots[0] = carry_s[...]
 
     return kernel

@@ -34,7 +34,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.layout import LANE, SUBLANE, wave_tile
-from repro.kernels.cache_wave.cache_wave import make_wave_kernel
+from repro.kernels.cache_wave.cache_wave import KC_CHUNK, make_wave_kernel
+from repro.kernels.knn.knn import lane_pad
 
 __all__ = ["LANE", "SUBLANE", "wave_tile", "wave_query_topk",
            "wave_insert_scatter", "wave_insert_query"]
@@ -63,71 +64,76 @@ def _check_state(doc_emb, doc_scale, tile_c):
         f"doc_scale must be stored f32, got {doc_scale.dtype}")
 
 
-def _common_specs(tile_c, dp):
-    """(ints SMEM, doc payload, doc ids, doc scale) input specs."""
-    return [
-        pl.BlockSpec((1, 8), lambda i, t: (i, 0), memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, tile_c, dp), lambda i, t: (i, t, 0)),
-        pl.BlockSpec((1, tile_c), lambda i, t: (i, t)),
-        pl.BlockSpec((1, tile_c), lambda i, t: (i, t)),
-    ]
+VMEM_LIMIT = 64 * 2 ** 20
+
+
+def _rows(x):
+    """(S, X) -> (S, 1, X): per-row vectors ride (1, 1, X-tile) blocks,
+    whose last two dims the TPU lowering accepts (a (1, X) block over an
+    (S, X) array it refuses)."""
+    return x[:, None, :]
 
 
 def _launch(*, s, capacity, dp, kc, qmax, k, tile_c, store_dtype,
             radius_dtype, with_insert, with_query, interpret, operands):
     tiles = capacity // tile_c
-    in_specs = _common_specs(tile_c, dp)
+    kp = lane_pad(k)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)           # whole (S, 8)
+    tile_row = pl.BlockSpec((1, 1, tile_c), lambda i, t: (i, 0, t))
+    in_specs = [
+        smem,                                                      # ints
+        pl.BlockSpec((1, tile_c, dp), lambda i, t: (i, t, 0)),     # payload
+        tile_row,                                                  # doc ids
+        tile_row,                                                  # scales
+    ]
     out_specs, out_shape, scratch = [], [], []
     if with_insert:
+        def whole_row(n):
+            return pl.BlockSpec((1, 1, n), lambda i, t: (i, 0, 0))
+
         in_specs += [
-            pl.BlockSpec((1, tile_c), lambda i, t: (i, t)),        # stamps
-            pl.BlockSpec((1, 8), lambda i, t: (i, 0),
-                         memory_space=pltpu.SMEM),                 # floats
+            tile_row,                                              # stamps
+            smem,                                                  # floats
             pl.BlockSpec((1, kc, dp), lambda i, t: (i, 0, 0)),     # new emb
-            pl.BlockSpec((1, 1, kc), lambda i, t: (i, 0, 0)),      # emb scale
-            pl.BlockSpec((1, 1, kc), lambda i, t: (i, 0, 0)),      # new ids
-            pl.BlockSpec((1, 1, kc), lambda i, t: (i, 0, 0)),      # positions
+            whole_row(kc),                                         # emb scale
+            whole_row(kc),                                         # new ids
+            whole_row(kc),                                         # positions
             pl.BlockSpec((1, 8, dp), lambda i, t: (i, 0, 0)),      # psi store
             pl.BlockSpec((1, qmax, dp), lambda i, t: (i, 0, 0)),   # q_emb
-            pl.BlockSpec((1, qmax), lambda i, t: (i, 0)),          # q_radius
-            pl.BlockSpec((1, qmax), lambda i, t: (i, 0)),          # q_scale
+            whole_row(qmax),                                       # q_radius
+            whole_row(qmax),                                       # q_scale
         ]
         out_specs += [
             pl.BlockSpec((1, tile_c, dp), lambda i, t: (i, t, 0)),
-            pl.BlockSpec((1, tile_c), lambda i, t: (i, t)),
-            pl.BlockSpec((1, tile_c), lambda i, t: (i, t)),
-            pl.BlockSpec((1, tile_c), lambda i, t: (i, t)),
+            tile_row, tile_row, tile_row,
             pl.BlockSpec((1, qmax, dp), lambda i, t: (i, 0, 0)),
-            pl.BlockSpec((1, qmax), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, qmax), lambda i, t: (i, 0)),
+            whole_row(qmax), whole_row(qmax),
         ]
         out_shape += [
             jax.ShapeDtypeStruct((s, capacity, dp), store_dtype),
-            jax.ShapeDtypeStruct((s, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((s, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((s, capacity), jnp.float32),
+            jax.ShapeDtypeStruct((s, 1, capacity), jnp.int32),
+            jax.ShapeDtypeStruct((s, 1, capacity), jnp.int32),
+            jax.ShapeDtypeStruct((s, 1, capacity), jnp.float32),
             jax.ShapeDtypeStruct((s, qmax, dp), store_dtype),
-            jax.ShapeDtypeStruct((s, qmax), radius_dtype),
-            jax.ShapeDtypeStruct((s, qmax), jnp.float32),
+            jax.ShapeDtypeStruct((s, 1, qmax), radius_dtype),
+            jax.ShapeDtypeStruct((s, 1, qmax), jnp.float32),
         ]
+    if with_insert:
+        scratch += [pltpu.VMEM((tile_c, dp), jnp.float32)]      # scatter acc
     if with_query:
         in_specs += [
             pl.BlockSpec((1, 8, dp), lambda i, t: (i, 0, 0)),      # psi f32
         ]
-        out_specs += [
-            pl.BlockSpec((1, k), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, t: (i, 0)),
-        ]
+        out_specs += [pl.BlockSpec((1, 1, kp), lambda i, t: (i, 0, 0))] * 3
         out_shape += [
-            jax.ShapeDtypeStruct((s, k), jnp.float32),
-            jax.ShapeDtypeStruct((s, k), jnp.int32),
-            jax.ShapeDtypeStruct((s, k), jnp.int32),
+            jax.ShapeDtypeStruct((s, 1, kp), jnp.float32),
+            jax.ShapeDtypeStruct((s, 1, kp), jnp.int32),
+            jax.ShapeDtypeStruct((s, 1, kp), jnp.int32),
         ]
         scratch += [
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.int32),
-            pltpu.VMEM((1, k), jnp.int32),
+            pltpu.VMEM((1, kp), jnp.float32),
+            pltpu.VMEM((1, kp), jnp.int32),
+            pltpu.VMEM((1, kp), jnp.int32),
         ]
     kernel = make_wave_kernel(tile_c=tile_c, tiles=tiles, kc=kc, k=k,
                               with_insert=with_insert, with_query=with_query)
@@ -140,7 +146,7 @@ def _launch(*, s, capacity, dp, kc, qmax, k, tile_c, store_dtype,
         flops=2 * s * capacity * dp * ((kc if with_insert else 0)
                                        + (1 if with_query else 0)),
         bytes_accessed=payload + batch, transcendentals=0)
-    return pl.pallas_call(
+    outs = list(pl.pallas_call(
         kernel,
         grid=(s, tiles),
         in_specs=in_specs,
@@ -148,8 +154,19 @@ def _launch(*, s, capacity, dp, kc, qmax, k, tile_c, store_dtype,
         out_shape=out_shape,
         scratch_shapes=scratch,
         cost_estimate=cost,
+        # a double-buffered fp32 payload tile in and out plus the k_c batch
+        # block (~24 MB at 512 x 896 x 1000) passes the 16 MB default scoped
+        # VMEM limit; v5e has 128 MB of VMEM
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(*operands)
+    )(*operands))
+    # back to the CacheState row layout; query answers trimmed to k
+    if with_insert:
+        outs[1:4] = [x.reshape(s, capacity) for x in outs[1:4]]
+        outs[5:7] = [x.reshape(s, qmax) for x in outs[5:7]]
+    if with_query:
+        outs[-3:] = [x.reshape(s, kp)[:, :k] for x in outs[-3:]]
+    return outs
 
 
 def _psi_block(psi, dp):
@@ -175,7 +192,7 @@ def wave_query_topk(doc_emb, doc_ids, doc_scale, psi, k: int,
     tile_c = wave_tile(capacity)
     _check_state(doc_emb, doc_scale, tile_c)
     ints = jnp.zeros((s, 8), jnp.int32)
-    operands = (ints, doc_emb, doc_ids, doc_scale,
+    operands = (ints, doc_emb, _rows(doc_ids), _rows(doc_scale),
                 _psi_block(psi.astype(jnp.float32), d))
     return _launch(
         s=s, capacity=capacity, dp=d, kc=0, qmax=0, k=k,
@@ -199,16 +216,20 @@ def _insert_operands(doc_emb, doc_ids, doc_stamp, doc_scale, q_emb, q_radius,
         f" {d}): pass a pre-padded CacheState")
     assert q_scale.dtype == jnp.float32, "q_scale must be stored f32"
     assert emb_q.shape[2] <= d and psi_q.shape[1] <= d
-    # one fused pad per wave input (rows to the sublane, features to the
-    # state's physical width) — two chained pads would materialize twice
-    emb_p = jnp.pad(emb_q, ((0, 0), (0, (-emb_q.shape[1]) % SUBLANE),
+    # one fused pad per wave input (rows to the kernel's scatter step: the
+    # sublane for a batch of at most KC_CHUNK rows, KC_CHUNK above that;
+    # features to the state's physical width) — two chained pads would
+    # materialize twice
+    kc = emb_q.shape[1]
+    mult = SUBLANE if kc <= KC_CHUNK else KC_CHUNK
+    emb_p = jnp.pad(emb_q, ((0, 0), (0, (-kc) % mult),
                             (0, d - emb_q.shape[2])))
     kc_p = emb_p.shape[1]
-    escale = _pad_axis(emb_scale.astype(jnp.float32), 1, SUBLANE,
+    escale = _pad_axis(emb_scale.astype(jnp.float32), 1, mult,
                        value=1.0)[:, None, :]
-    nids = _pad_axis(new_ids.astype(jnp.int32), 1, SUBLANE,
+    nids = _pad_axis(new_ids.astype(jnp.int32), 1, mult,
                      value=-1)[:, None, :]
-    pos_p = _pad_axis(pos.astype(jnp.int32), 1, SUBLANE,
+    pos_p = _pad_axis(pos.astype(jnp.int32), 1, mult,
                       value=capacity)[:, None, :]
     psis = jnp.pad(psi_q[:, None, :],
                    ((0, 0), (0, SUBLANE - 1), (0, d - psi_q.shape[1])))
@@ -222,8 +243,9 @@ def _insert_operands(doc_emb, doc_ids, doc_stamp, doc_scale, q_emb, q_radius,
         jnp.asarray(radius, jnp.float32),
         jnp.asarray(psi_scale, jnp.float32),
     ] + [jnp.zeros((s,), jnp.float32)] * 6, axis=1)
-    operands = (ints, doc_emb, doc_ids, doc_scale, doc_stamp, floats, emb_p,
-                escale, nids, pos_p, psis, q_emb, q_radius, q_scale)
+    operands = (ints, doc_emb, _rows(doc_ids), _rows(doc_scale),
+                _rows(doc_stamp), floats, emb_p, escale, nids, pos_p, psis,
+                q_emb, _rows(q_radius), _rows(q_scale))
     dims = dict(s=s, capacity=capacity, dp=d, kc=kc_p, qmax=q_emb.shape[1],
                 tile_c=tile_c, store_dtype=doc_emb.dtype,
                 radius_dtype=q_radius.dtype)

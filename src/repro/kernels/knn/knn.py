@@ -65,6 +65,56 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
+LANE = 128
+# f32 dots run at full f32 precision on every tier (the MXU's default would
+# round f32 operands to bf16), so kernel and ref rankings agree on the chip
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def lane_pad(k: int) -> int:
+    """Carry width: k rounded up to the lane multiple, so every carry
+    update is a full-width masked select (Mosaic refuses stores at a
+    dynamic lane offset that it cannot prove 128-aligned)."""
+    return -(-k // LANE) * LANE
+
+
+def extract_topk(pool_v, pool_ids, out_v, out_ids, *, k: int, knock: float,
+                 empty_id=None):
+    """Move the ``k`` best entries of a candidate pool, best first, into
+    columns 0..k-1 of the lane-padded ``out`` carries.
+
+    ``pool_v`` (B, P) holds the values and ``pool_ids`` a tuple of int32
+    (B, P) payloads riding with them (ids, slots); ``out_v`` / ``out_ids``
+    are the matching (B, Kp) carries.  Iterative max-extract on the VPU;
+    argmax's first match breaks ties by pool position.  Each step writes
+    its column with a full-width ``jnp.where`` (lane-aligned, unlike a
+    one-column store), then knocks the winner out of the pool with
+    ``knock``.  ``empty_id`` (when given) replaces the payloads of an
+    extracted -inf value.  Returns the new carries.
+    """
+    col = jax.lax.broadcasted_iota(jnp.int32, pool_v.shape, 1)
+    out_col = jax.lax.broadcasted_iota(jnp.int32, out_v.shape, 1)
+
+    def extract(j, state):
+        s, ov, ois = state
+        m = jnp.max(s, axis=1, keepdims=True)                   # (B, 1)
+        a = jnp.argmax(s, axis=1).astype(jnp.int32)[:, None]    # (B, 1)
+        hit = col == a
+        put = out_col == j
+        new_ois = []
+        for pool_i, oi in zip(pool_ids, ois):
+            # one-hot reduce instead of a gather: payload at the extracted
+            # column
+            picked = jnp.sum(jnp.where(hit, pool_i, 0), axis=1,
+                             keepdims=True).astype(jnp.int32)
+            if empty_id is not None:
+                picked = jnp.where(m == NEG_INF, empty_id, picked)
+            new_ois.append(jnp.where(put, picked, oi))
+        return jnp.where(hit, knock, s), jnp.where(put, m, ov), tuple(new_ois)
+
+    _, out_v, out_ids = jax.lax.fori_loop(
+        0, k, extract, (pool_v, out_v, tuple(out_ids)))
+    return out_v, out_ids
 
 
 def _masked_scores(q, docs, ids, scale, q_scale=None, *, int8_dot=False):
@@ -90,36 +140,26 @@ def _masked_scores(q, docs, ids, scale, q_scale=None, *, int8_dot=False):
     else:
         scores = jax.lax.dot_general(
             q.astype(jnp.float32), docs.astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
+            (((1,), (1,)), ((), ())), precision=HIGHEST,
             preferred_element_type=jnp.float32)        # (B, TILE_N)
         scores = scores * scale
     return jnp.where(ids < 0, NEG_INF, scores)
 
 
 def _merge_tile_into_carry(scores, ids, carry_v, carry_i, *, k: int):
-    """Merge one tile's (B, TILE_N) scores into the (B, k) VMEM carry.
+    """Merge one tile's (B, TILE_N) scores into the (B, Kp) VMEM carry.
 
     candidate pool = running carry ++ this tile; carry columns come first,
     so equal scores resolve to the earliest corpus position — the same
-    tie-break a stable global lax.top_k applies.
+    tie-break a stable global lax.top_k applies.  Carry columns past ``k``
+    (lane padding) stay -inf / -1.
     """
     cand_v = jnp.concatenate([carry_v[...], scores], axis=1)
     cand_i = jnp.concatenate(
         [carry_i[...], jnp.broadcast_to(ids, scores.shape)], axis=1)
-    col = jax.lax.broadcasted_iota(jnp.int32, cand_v.shape, 1)
-
-    def extract(j, s):
-        m = jnp.max(s, axis=1)                             # (B,)
-        a = jnp.argmax(s, axis=1).astype(jnp.int32)        # (B,)
-        hit = col == a[:, None]
-        # one-hot reduce instead of a gather: id at the extracted column
-        picked = jnp.sum(jnp.where(hit, cand_i, 0), axis=1).astype(jnp.int32)
-        picked = jnp.where(m == NEG_INF, -1, picked)       # sentinel, not id
-        carry_v[:, pl.dslice(j, 1)] = m[:, None]
-        carry_i[:, pl.dslice(j, 1)] = picked[:, None]
-        return jnp.where(hit, NEG_INF, s)
-
-    jax.lax.fori_loop(0, k, extract, cand_v)
+    carry_v[...], (carry_i[...],) = extract_topk(
+        cand_v, (cand_i,), carry_v[...], (carry_i[...],), k=k,
+        knock=NEG_INF, empty_id=-1)
 
 
 def _fused_kernel(q_ref, qscale_ref, docs_hbm, ids_hbm, scale_hbm,
@@ -144,10 +184,9 @@ def _fused_kernel(q_ref, qscale_ref, docs_hbm, ids_hbm, scale_hbm,
                 docs_hbm.at[pl.ds(t * tile_n, tile_n)],
                 docs_buf.at[slot], docs_sem.at[slot]),
             pltpu.make_async_copy(
-                ids_hbm.at[pl.ds(t, 1)], ids_buf.at[slot], ids_sem.at[slot]),
+                ids_hbm.at[t], ids_buf.at[slot], ids_sem.at[slot]),
             pltpu.make_async_copy(
-                scale_hbm.at[pl.ds(t, 1)], scale_buf.at[slot],
-                scale_sem.at[slot]),
+                scale_hbm.at[t], scale_buf.at[slot], scale_sem.at[slot]),
         )
 
     for dma in tile_dmas(0, 0):                            # warm-up: tile 0
@@ -212,46 +251,51 @@ def knn_fused_topk(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array,
     b = queries.shape[0]
     assert n % tile_n == 0
     tiles = n // tile_n
-    ids_2d = doc_ids.reshape(tiles, tile_n)
+    # (tiles, 1, TILE_N): each tile's id / scale row is a slice of the
+    # untiled leading axis — a one-row slice of a tiled axis is not a legal
+    # DMA source on the chip
+    ids_3d = doc_ids.reshape(tiles, 1, tile_n)
     if scale is None:
         scale = jnp.ones((n,), jnp.float32)
-    scale_2d = scale.astype(jnp.float32).reshape(tiles, tile_n)
+    scale_3d = scale.astype(jnp.float32).reshape(tiles, 1, tile_n)
     if q_scale is None:
         q_scale = jnp.ones((b,), jnp.float32)
     qscale_col = q_scale.astype(jnp.float32).reshape(b, 1)
     kernel = functools.partial(_fused_kernel, k=k, tile_n=tile_n,
                                tiles=tiles, int8_dot=int8_dot)
     itemsize = jnp.dtype(docs.dtype).itemsize
-    return pl.pallas_call(
+    kp = lane_pad(k)
+    vals, idx = pl.pallas_call(
         kernel,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),         # queries: resident
             pl.BlockSpec(memory_space=pltpu.VMEM),         # per-query scales
-            pl.BlockSpec(memory_space=pltpu.ANY),          # corpus: HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),          # tile ids: HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),          # doc scales: HBM
+            pl.BlockSpec(memory_space=pl.ANY),             # corpus: HBM
+            pl.BlockSpec(memory_space=pl.ANY),             # tile ids: HBM
+            pl.BlockSpec(memory_space=pl.ANY),             # doc scales: HBM
         ],
         out_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
+            jax.ShapeDtypeStruct((b, kp), jnp.float32),
+            jax.ShapeDtypeStruct((b, kp), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, tile_n, d), docs.dtype),        # double tile buf
             pltpu.VMEM((2, 1, tile_n), jnp.int32),         # double id buf
             pltpu.VMEM((2, 1, tile_n), jnp.float32),       # double scale buf
-            pltpu.VMEM((b, k), jnp.float32),               # running top-k vals
-            pltpu.VMEM((b, k), jnp.int32),                 # running top-k ids
+            pltpu.VMEM((b, kp), jnp.float32),              # running top-k vals
+            pltpu.VMEM((b, kp), jnp.int32),                # running top-k ids
             pltpu.SemaphoreType.DMA((2,)),                 # docs DMA sems
             pltpu.SemaphoreType.DMA((2,)),                 # ids DMA sems
             pltpu.SemaphoreType.DMA((2,)),                 # scale DMA sems
         ],
         cost_estimate=_scan_cost(n, d, b, k, itemsize, int8_dot),
         interpret=interpret,
-    )(queries, qscale_col, docs, ids_2d, scale_2d)
+    )(queries, qscale_col, docs, ids_3d, scale_3d)
+    return vals[:, :k], idx[:, :k]
 
 
 def _knn_kernel(q_ref, qscale_ref, docs_ref, ids_ref, scale_ref, out_vals_ref,
@@ -260,24 +304,18 @@ def _knn_kernel(q_ref, qscale_ref, docs_ref, ids_ref, scale_ref, out_vals_ref,
     tile = pl.program_id(0)
     q = q_ref[...]                      # (B, D) f32 — or int8 payload
     docs = docs_ref[...]                # (TILE_N, D) any dtype
-    ids = ids_ref[...]                  # (1, TILE_N) int32
-    scale = scale_ref[...]              # (1, TILE_N) f32
+    ids = ids_ref[0]                    # (1, TILE_N) int32
+    scale = scale_ref[0]                # (1, TILE_N) f32
     # same data-driven validity as the fused kernel: sentinel rows (id < 0)
     # can never win a per-tile extraction, wherever they sit in the corpus
     scores = _masked_scores(q, docs, ids, scale, qscale_ref[...],
                             int8_dot=int8_dot)            # (B, TILE_N)
-    base = tile * tile_n
-
-    def body(j, s):
-        m = jnp.max(s, axis=1)                         # (B,)
-        a = jnp.argmax(s, axis=1).astype(jnp.int32)    # (B,)
-        out_vals_ref[0, :, pl.dslice(j, 1)] = m[:, None]
-        out_idx_ref[0, :, pl.dslice(j, 1)] = (base + a)[:, None]
-        # knock out the extracted column per row
-        hit = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) == a[:, None]
-        return jnp.where(hit, NEG_INF, s)
-
-    jax.lax.fori_loop(0, k, body, scores)
+    pos = tile * tile_n + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    out_v, (out_i,) = extract_topk(
+        scores, (pos,), jnp.full(out_vals_ref.shape[1:], NEG_INF, jnp.float32),
+        (jnp.zeros(out_idx_ref.shape[1:], jnp.int32),), k=k, knock=NEG_INF)
+    out_vals_ref[0] = out_v
+    out_idx_ref[0] = out_i
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tile_n", "interpret",
@@ -300,34 +338,38 @@ def knn_tile_topk(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array,
     b = queries.shape[0]
     assert n % tile_n == 0 and k <= tile_n
     tiles = n // tile_n
-    ids_2d = doc_ids.reshape(tiles, tile_n)
+    # (tiles, 1, TILE_N) rows: a (1, 1, TILE_N) block keeps the last two
+    # block dims whole-or-lane-aligned, as the TPU lowering requires
+    ids_3d = doc_ids.reshape(tiles, 1, tile_n)
     if scale is None:
         scale = jnp.ones((n,), jnp.float32)
-    scale_2d = scale.astype(jnp.float32).reshape(tiles, tile_n)
+    scale_3d = scale.astype(jnp.float32).reshape(tiles, 1, tile_n)
     if q_scale is None:
         q_scale = jnp.ones((b,), jnp.float32)
     qscale_col = q_scale.astype(jnp.float32).reshape(b, 1)
     kernel = functools.partial(_knn_kernel, k=k, tile_n=tile_n,
                                int8_dot=int8_dot)
     itemsize = jnp.dtype(docs.dtype).itemsize
-    return pl.pallas_call(
+    kp = lane_pad(k)
+    vals, idx = pl.pallas_call(
         kernel,
         grid=(tiles,),
         in_specs=[
             pl.BlockSpec((b, d), lambda i: (0, 0)),        # queries: resident
             pl.BlockSpec((b, 1), lambda i: (0, 0)),        # per-query scales
             pl.BlockSpec((tile_n, d), lambda i: (i, 0)),   # corpus tile stream
-            pl.BlockSpec((1, tile_n), lambda i: (i, 0)),   # tile ids
-            pl.BlockSpec((1, tile_n), lambda i: (i, 0)),   # tile doc scales
+            pl.BlockSpec((1, 1, tile_n), lambda i: (i, 0, 0)),  # tile ids
+            pl.BlockSpec((1, 1, tile_n), lambda i: (i, 0, 0)),  # tile scales
         ],
         out_specs=[
-            pl.BlockSpec((1, b, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, b, k), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, b, kp), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, b, kp), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((tiles, b, k), jnp.float32),
-            jax.ShapeDtypeStruct((tiles, b, k), jnp.int32),
+            jax.ShapeDtypeStruct((tiles, b, kp), jnp.float32),
+            jax.ShapeDtypeStruct((tiles, b, kp), jnp.int32),
         ],
         cost_estimate=_scan_cost(n, d, b, k, itemsize, int8_dot),
         interpret=interpret,
-    )(queries, qscale_col, docs, ids_2d, scale_2d)
+    )(queries, qscale_col, docs, ids_3d, scale_3d)
+    return vals[..., :k], idx[..., :k]

@@ -91,7 +91,9 @@ def _ref_search(docs, doc_ids, queries, k, scale=None, int8_dot=False):
             preferred_element_type=jnp.int32)
         scores = acc.astype(jnp.float32) * qq.scale[:, None]
     else:
-        scores = queries.astype(jnp.float32) @ docs.astype(jnp.float32).T
+        scores = jnp.matmul(queries.astype(jnp.float32),
+                            docs.astype(jnp.float32).T,
+                            precision=jax.lax.Precision.HIGHEST)
     if scale is not None:
         scores = scores * scale.astype(jnp.float32)[None, :]
     scores = jnp.where(doc_ids[None, :] < 0, NEG_INF, scores)

@@ -133,7 +133,6 @@ def moe_ffn_sharded(params: dict, x: jax.Array, cfg: MoEConfig, mesh,
     Megatron-style EP+TP hybrid with no all-to-all (the psum replaces it;
     an a2a variant is a recorded §Perf candidate).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist.api import data_axes
 
@@ -203,12 +202,12 @@ def moe_ffn_sharded(params: dict, x: jax.Array, cfg: MoEConfig, mesh,
         y = jax.lax.psum(y, "model")        # combine expert partials (EP)
         return y, aux
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         jax.checkpoint(local_fn, prevent_cse=False), mesh=mesh,
         in_specs=(P(dp if dp else None, None), P(None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(P(dp if dp else None, None), P()),
-        check_rep=False)
+        check_vma=False)
     y, aux = sharded(x, params["router"], params["wi"], params["wo"])
     if "shared_wi" in params:
         y = y + _swiglu(x, params["shared_wi"], params["shared_wo"])

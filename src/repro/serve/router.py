@@ -113,6 +113,7 @@ class RouterStats:
     breaker_opens: int = 0
     breaker_closes: int = 0
     shed: int = 0          # whole searches refused: every breaker open
+    last_error: str = ""   # the newest failed shard call, for diagnosis
     per_shard: list = dataclasses.field(default_factory=list)
     _lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock, repr=False, compare=False)
@@ -272,11 +273,13 @@ class ShardedRouter:
 
     @classmethod
     def over_devices(cls, docs, doc_ids=None, *, devices=None,
-                     chunk: int = 4096, **kwargs) -> "ShardedRouter":
-        """Router fronting device-sharded corpus slices (one per device)."""
+                     chunk: int = 4096, dtype: Optional[str] = None,
+                     **kwargs) -> "ShardedRouter":
+        """Router fronting device-sharded corpus slices (one per device);
+        ``dtype`` is the shards' storage format (``make_device_shards``)."""
         from repro.dist.retrieval import make_device_shards
         return cls(make_device_shards(docs, doc_ids, devices=devices,
-                                      chunk=chunk), **kwargs)
+                                      chunk=chunk, dtype=dtype), **kwargs)
 
     # --------------------------------------------------------- lifecycle
     def close(self) -> None:
@@ -331,14 +334,18 @@ class ShardedRouter:
                 self.breakers[i].record(True)
                 self.stats.shard_bump(i, "calls")
                 return ans
-            except AnswerValidationError:
+            except AnswerValidationError as e:
+                self.stats.last_error = f"shard {i}: {e!r}"
                 self.breakers[i].record(False)
                 self.stats.bump("rejected")
                 self.stats.shard_bump(i, "rejected")
                 self.stats.shard_bump(i, "calls")
                 if self.telemetry is not None:
                     self.telemetry.record_fault("rejected_answers")
-            except Exception:
+            except Exception as e:            # noqa: BLE001
+                # counted, retried and shown, never silent: a kernel that
+                # fails to compile or run surfaces here as the cause
+                self.stats.last_error = f"shard {i}: {e!r}"
                 self.breakers[i].record(False)
                 self.stats.shard_bump(i, "failures")
                 self.stats.shard_bump(i, "calls")

@@ -282,9 +282,11 @@ class BatchedEngine:
         # pad the wave with copies of row 0 (probe-only: do/need are forced
         # False and padded rows are never scattered back or reported)
         pad_sids = np.concatenate([sids, np.repeat(sids[:1], bucket - wave)])
-        q = jnp.stack([jnp.asarray(x) for x in queries])
-        q = jnp.concatenate([q, jnp.broadcast_to(q[:1], (bucket - wave,)
-                                                 + q.shape[1:])])
+        # assembled on the host and sent once at the bucket shape, so a new
+        # wave size compiles no program of its own
+        q = np.stack([np.asarray(x) for x in queries])
+        q = jnp.asarray(np.concatenate(
+            [q, np.broadcast_to(q[:1], (bucket - wave,) + q.shape[1:])]))
         psi = self.encoder(q) if self.encoder else q
 
         sub = self.cache.gather(pad_sids)
@@ -429,10 +431,12 @@ class BatchedEngine:
                             "back-end answer holds no valid docs")
                     # r_a per row from the last *valid* column (short
                     # merges are sentinel-padded); same guard as the
-                    # sequential engine
+                    # sequential engine.  Computed at the bucket length,
+                    # so each bucket compiles it once, not each miss count
+                    last = np.take_along_axis(
+                        ans.scores, n_valid[:, None] - 1, axis=1)[:, 0]
                     radii = np.asarray(distance_from_scores(jnp.asarray(
-                        np.take_along_axis(ans.scores, n_valid[:, None] - 1,
-                                           axis=1)[:, 0])))
+                        np.pad(last, (0, bucket - len(last))))))[:len(last)]
                     ws.new_ids[miss, :self.k_c] = ans.ids
                     ws.new_emb[miss, :self.k_c] = self.doc_embeddings[
                         np.maximum(ans.ids, 0)]
@@ -544,12 +548,17 @@ class BatchedEngine:
         else:  # missless (or outage) wave: probe -> query
             (scores, _dists, ids, _slots), sub = query_batched(
                 ws.sub, ws.psi, self.k, backend=self.backend)
-        able = np.nonzero(~ws.failed[:ws.wave])[0]
+        # the launch consumed the gathered rows: release them before the
+        # write-back, which holds old and new stacked state at once
+        ws.sub = None
         # write back only real, answerable rows (padded rows are shadows of
         # row 0; failed rows must stay exactly as they were, like a
-        # sequential engine raising before its cache query)
-        self.cache.scatter(ws.sids[able],
-                           jax.tree_util.tree_map(lambda x: x[able], sub))
+        # sequential engine raising before its cache query) — every other
+        # row routes to the drop index
+        rows = np.full((ws.bucket,), self.n_sessions, np.int32)
+        able = np.nonzero(~ws.failed[:ws.wave])[0]
+        rows[able] = ws.sids[able]
+        self.cache.scatter(rows, sub)
         if self.shared is not None:
             # end-of-wave: promote the wave's admitted answers into their
             # shards (deferred so admission never adds launches mid-wave)

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.configs import registry
 from repro.core import embedding as emb
@@ -25,7 +25,8 @@ def test_multi_device_topology():
 
 
 def _mesh(shape, axes):
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _corpus(n, dim, seed=0, n_dup=0, n_queries=5):
@@ -241,3 +242,45 @@ def test_router_over_devices_constructor():
     assert not degraded and ans.ids.shape == (2, 10)
     np.testing.assert_array_equal(
         ans.ids, np.asarray(index.search(jnp.asarray(q), 10).ids))
+
+
+def test_device_shards_place_each_slice_on_its_own_device():
+    """Each shard's payload, ids and scales live on its own device only,
+    stored lane-aligned with sentinel-padded rows; the shards together
+    answer like the single-device index."""
+    from repro.serve.router import ShardedRouter
+    rng = np.random.default_rng(11)
+    raw = rng.standard_normal((3000, 40)).astype(np.float32)
+    index = MetricIndex(jnp.asarray(raw))
+    docs = np.asarray(index.dequantized())[:index.n_docs]
+    devices = jax.devices()[:4]
+    shards = dr.make_device_shards(docs, devices=devices, dtype="int8")
+    for sh, dev in zip(shards, devices):
+        for x in (sh.docs, sh.doc_ids, sh.scale):
+            assert x.devices() == {dev}
+        assert sh.docs.dtype == jnp.int8 and sh.docs.shape[1] % 128 == 0
+        assert sh.docs.shape[0] % sh.chunk == 0
+        pad = np.asarray(sh.doc_ids) < 0
+        assert (np.asarray(sh.scale)[pad] == 1).all()
+        assert not np.asarray(sh.docs)[pad].any()
+    router = ShardedRouter(shards, deadline_s=30)
+    q = np.asarray(index.transform_queries(jnp.asarray(
+        rng.standard_normal((3, 40)).astype(np.float32))))
+    ans, degraded = router.search(q, 12)
+    ref = MetricIndex(jnp.asarray(docs), transformed=True, dtype="int8")
+    assert not degraded
+    np.testing.assert_array_equal(ans.ids,
+                                  np.asarray(ref.search(jnp.asarray(q), 12).ids))
+    router.close()
+
+
+def test_shard_corpus_lays_host_rows_over_the_mesh():
+    rng = np.random.default_rng(12)
+    docs = rng.standard_normal((1000, 24)).astype(np.float32)
+    ids = np.arange(1000, dtype=np.int32)
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("shard",))
+    d, i, s, _, chunk = dr.shard_corpus(docs, ids, mesh=mesh, chunk=64)
+    assert s is None and d.shape[0] % (4 * chunk) == 0
+    assert len(d.sharding.device_set) == 4
+    np.testing.assert_array_equal(np.asarray(d)[:1000], docs)
+    assert (np.asarray(i)[1000:] == -1).all()

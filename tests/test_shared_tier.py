@@ -100,6 +100,42 @@ def test_ttl_expires_claims_but_not_documents():
     assert tier.contains(ids).all()           # ...documents survive
 
 
+def test_flush_runs_the_one_program_warm_compiles():
+    """Every admission flush, whatever the number of admitted answers,
+    launches the whole-state insert program ``warm`` compiles, and the
+    answer query runs the programs it compiled per wave size; ``warm``
+    and the shards without an admitted answer keep their state."""
+    from repro.core.cache_ops import insert_batched, query_batched
+    tier = SharedTier(dim=32, n_shards=3, capacity=60, max_queries=5,
+                      admission_sessions=1, backend="interpret")
+    rng = np.random.default_rng(11)
+    leaves = jax.tree_util.tree_leaves
+    before = tier.state
+    tier.warm(k=4, k_c=6, rows=[1, 2], backend="interpret")
+    assert tier.state is before
+    programs = (insert_batched._cache_size(), query_batched._cache_size())
+    tier.tick()
+    psi = _unit(rng, (6, 32))
+    assert tier.offer(("a", 1), psi[0], 0.5, _unit(rng, (6, 32)),
+                      np.arange(6))
+    before = tier.state
+    assert tier.flush_admissions() == 1
+    others = np.arange(3) != tier.route(psi[:1])[0]
+    for a, b in zip(leaves(before), leaves(tier.state)):
+        np.testing.assert_array_equal(np.asarray(a)[others],
+                                      np.asarray(b)[others])
+    assert tier.contains(np.arange(6)).all()
+    for j in range(1, 6):
+        assert tier.offer(("a", 1), psi[j], 0.5, _unit(rng, (6, 32)),
+                          np.arange(10 * j, 10 * j + 6))
+    assert tier.flush_admissions() == 5
+    assert tier.contains(np.arange(10, 56)[np.arange(46) % 10 < 6]).all()
+    for b in (1, 2):
+        tier.query_rows(psi[:b], tier.route(psi[:b]), 4)
+    assert (insert_batched._cache_size(),
+            query_batched._cache_size()) == programs
+
+
 # ------------------------------------------------------ semantic result memo
 def test_memo_serves_other_sessions_only():
     tier = SharedTier(dim=32, backend="interpret")
