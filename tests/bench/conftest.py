@@ -1,0 +1,8 @@
+"""Puts the repository root on the path so ``bench`` imports."""
+
+import os
+import sys
+
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
