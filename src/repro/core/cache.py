@@ -21,6 +21,8 @@ the batched-variant / kernel-dispatch contract.
 
 from __future__ import annotations
 
+import threading
+
 import jax
 import jax.numpy as jnp
 
@@ -142,6 +144,12 @@ class BatchedMetricCache:
     The rows of the stacked ``CacheState`` are SESSIONS here (the L1 tier);
     ``repro.core.shared.SharedTier`` stacks the same state over SHARDS —
     same ops, same kernels, different row meaning.
+
+    Every write of ``state`` is a read-modify-write of the whole stack, and
+    the serving path makes them from several threads (a session opens on
+    its caller's thread while the scheduler's worker writes a wave back),
+    so each holds one lock: without it one write is lost, and a session
+    is answered from its slot's previous occupant.
     """
 
     def __init__(self, cfg: CacheConfig, n_sessions: int):
@@ -149,19 +157,22 @@ class BatchedMetricCache:
         self.n_sessions = n_sessions
         self.state = init_batched_cache(cfg, n_sessions)
         self.total_dropped = 0
+        self._write = threading.Lock()
 
     def reset(self, sessions=None):
         """Reset all sessions, or just the given session indices."""
         if sessions is None:
-            self.state = init_batched_cache(self.cfg, self.n_sessions)
-            self.total_dropped = 0
+            with self._write:
+                self.state = init_batched_cache(self.cfg, self.n_sessions)
+                self.total_dropped = 0
             return
         # write only the target rows (a fresh full stacked state per open
         # would make opening S sessions O(S^2) in state traffic)
         idx = jnp.asarray(sessions)
         fresh = init_cache(self.cfg)
-        self.state = jax.tree_util.tree_map(
-            lambda full, one: full.at[idx].set(one), self.state, fresh)
+        with self._write:
+            self.state = jax.tree_util.tree_map(
+                lambda full, one: full.at[idx].set(one), self.state, fresh)
 
     @property
     def n_docs(self):
@@ -182,7 +193,9 @@ class BatchedMetricCache:
         row j of ``sub`` lands in session ``sessions[j]``; an entry equal
         to ``n_sessions`` drops its row (padded or failed wave rows), so
         the caller never gathers a trimmed copy of ``sub`` first."""
-        self.state = _scatter_rows(self.state, jnp.asarray(sessions), sub)
+        idx = jnp.asarray(sessions)
+        with self._write:
+            self.state = _scatter_rows(self.state, idx, sub)
 
     def probe(self, psi, epsilon=None, backend=None) -> ProbeResult:
         eps = self.cfg.epsilon if epsilon is None else epsilon
@@ -190,14 +203,17 @@ class BatchedMetricCache:
                              max_queries=self.cfg.max_queries)
 
     def query(self, psi, k: int, backend=None):
-        out, self.state = query_batched(self.state, psi, k, backend=backend)
+        with self._write:
+            out, self.state = query_batched(self.state, psi, k,
+                                            backend=backend)
         return out
 
     def insert(self, psi, radius, new_emb, new_ids, do=None, record=None,
                backend=None):
-        self.state, dropped = insert_batched(
-            self.state, self.cfg, psi, radius, new_emb, new_ids, do, record,
-            backend=backend)
+        with self._write:
+            self.state, dropped = insert_batched(
+                self.state, self.cfg, psi, radius, new_emb, new_ids, do,
+                record, backend=backend)
         self.total_dropped += int(dropped.sum())
 
     def memory_bytes(self) -> int:

@@ -34,6 +34,7 @@ from repro.core import layout, quant
 from repro.core.metric_index import SearchResult, _as_result, scan_topk
 from repro.dist.api import active_mesh
 from repro.kernels import dispatch as kdispatch
+from repro.serve.telemetry import span
 
 __all__ = ["make_batched_scorer", "sharded_nn", "shard_corpus",
            "DeviceShard", "make_device_shards", "ShardTopK"]
@@ -298,8 +299,14 @@ class DeviceShard:
     construction, on its own device (``_place_slice``) and stored at the
     lane-aligned width the kernel tiers stream, so a search copies nothing
     of the corpus; queries are zero-padded to that width per call, and
-    their rows to a power of two.
+    their rows to a power of two.  A search is three spans —
+    ``shard.stage`` (the query put), ``shard.scan`` (the dispatch, not the
+    device time) and ``shard.fetch`` (the blocking read of scores and ids)
+    — and counts the bytes it moves into ``telemetry`` when one is set
+    (the router hands its own on).
     """
+
+    telemetry = None
 
     def __init__(self, docs, doc_ids, device=None, chunk: int = 4096,
                  backend: Optional[str] = None, dtype: Optional[str] = None):
@@ -327,12 +334,22 @@ class DeviceShard:
         # rows pad to a power of two and features to the stored width: a
         # free-running miss count compiles at most log2(wave) scan programs
         rows = 1 << max(b - 1, 0).bit_length()
-        q = np.pad(q, ((0, rows - b), (0, self.docs.shape[1] - q.shape[1])))
-        scores, ids = scan_topk(self.docs, self.doc_ids,
-                                jax.device_put(q, self.device), int(k),
-                                chunk=self.chunk, backend=self.backend,
-                                scale=self.scale, int8_dot=self.int8_dot)
-        return ShardTopK(np.asarray(scores)[:b], np.asarray(ids)[:b])
+        with span("shard.stage") as sp:
+            q = np.pad(q, ((0, rows - b),
+                           (0, self.docs.shape[1] - q.shape[1])))
+            q = jax.device_put(q, self.device)
+            sp.set_metadata(h2d_bytes=q.nbytes)
+        with span("shard.scan"):
+            scores, ids = scan_topk(self.docs, self.doc_ids, q, int(k),
+                                    chunk=self.chunk, backend=self.backend,
+                                    scale=self.scale, int8_dot=self.int8_dot)
+        with span("shard.fetch") as sp:
+            scores, ids = np.asarray(scores), np.asarray(ids)
+            sp.set_metadata(d2h_bytes=scores.nbytes + ids.nbytes)
+        if self.telemetry is not None:
+            self.telemetry.count(h2d_bytes=q.nbytes,
+                                 d2h_bytes=scores.nbytes + ids.nbytes)
+        return ShardTopK(scores[:b], ids[:b])
 
 
 def make_device_shards(docs, doc_ids=None, *, devices=None,

@@ -63,6 +63,7 @@ def probe_rhat(q_emb: jax.Array, psi: jax.Array, radius: jax.Array,
         out_specs=pl.BlockSpec((qmax, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((qmax, 1), jnp.float32),
         interpret=interpret,
+        name="probe_rhat",
     )(q_emb, psi, radius, scale)
 
 
@@ -96,4 +97,5 @@ def probe_rhat_batched(q_emb: jax.Array, psi: jax.Array, radius: jax.Array,
         out_specs=pl.BlockSpec((1, qmax, 1), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((s, qmax, 1), jnp.float32),
         interpret=interpret,
+        name="probe_rhat_batched",
     )(q_emb, psi, radius, scale)

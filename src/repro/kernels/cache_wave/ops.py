@@ -75,7 +75,8 @@ def _rows(x):
 
 
 def _launch(*, s, capacity, dp, kc, qmax, k, tile_c, store_dtype,
-            radius_dtype, with_insert, with_query, interpret, operands):
+            radius_dtype, with_insert, with_query, interpret, operands,
+            name):
     tiles = capacity // tile_c
     kp = lane_pad(k)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)           # whole (S, 8)
@@ -159,6 +160,7 @@ def _launch(*, s, capacity, dp, kc, qmax, k, tile_c, store_dtype,
         # VMEM limit; v5e has 128 MB of VMEM
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name=name,
     )(*operands))
     # back to the CacheState row layout; query answers trimmed to k
     if with_insert:
@@ -198,6 +200,7 @@ def wave_query_topk(doc_emb, doc_ids, doc_scale, psi, k: int,
         s=s, capacity=capacity, dp=d, kc=0, qmax=0, k=k,
         tile_c=tile_c, store_dtype=doc_emb.dtype, radius_dtype=jnp.float32,
         with_insert=False, with_query=True, interpret=interpret,
+        name="wave_query_topk",
         operands=operands)
 
 
@@ -270,7 +273,8 @@ def wave_insert_scatter(doc_emb, doc_ids, doc_stamp, doc_scale, q_emb,
         emb_q, emb_scale, new_ids, pos, psi_q, psi_scale, radius, rec,
         qslot, step_ins, tile_c)
     return _launch(**dims, k=0, with_insert=True, with_query=False,
-                   interpret=interpret, operands=operands)
+                   interpret=interpret, operands=operands,
+                   name="wave_insert_scatter")
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -291,5 +295,6 @@ def wave_insert_query(doc_emb, doc_ids, doc_stamp, doc_scale, q_emb,
         qslot, step_ins, tile_c)
     operands = operands + (_psi_block(psi.astype(jnp.float32), d),)
     outs = _launch(**dims, k=k, with_insert=True, with_query=True,
-                   interpret=interpret, operands=operands)
+                   interpret=interpret, operands=operands,
+                   name="wave_insert_query")
     return tuple(outs[:7]), tuple(outs[7:])

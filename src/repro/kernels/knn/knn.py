@@ -294,6 +294,7 @@ def knn_fused_topk(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array,
         ],
         cost_estimate=_scan_cost(n, d, b, k, itemsize, int8_dot),
         interpret=interpret,
+        name="knn_fused_topk",
     )(queries, qscale_col, docs, ids_3d, scale_3d)
     return vals[:, :k], idx[:, :k]
 
@@ -371,5 +372,6 @@ def knn_tile_topk(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array,
         ],
         cost_estimate=_scan_cost(n, d, b, k, itemsize, int8_dot),
         interpret=interpret,
+        name="knn_tile_topk",
     )(queries, qscale_col, docs, ids_3d, scale_3d)
     return vals[..., :k], idx[..., :k]

@@ -46,6 +46,8 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro.serve.telemetry import span
+
 __all__ = ["ShardAnswer", "RouterStats", "CircuitBreaker", "ShardedRouter",
            "AnswerValidationError", "validate_answer"]
 
@@ -242,6 +244,10 @@ class ShardedRouter:
 
     Owns a thread pool: ``close()`` it (or use the router as a context
     manager) so worker threads don't leak across benchmark runs/tests.
+
+    ``telemetry`` (a ``ServeTelemetry``, or None) counts faults, breaker
+    transitions and — handed on to every shard that has a ``telemetry``
+    attribute — the bytes device shards stage and read back.
     """
 
     def __init__(self, shards: Sequence[Callable], deadline_s: float = 1.0,
@@ -268,8 +274,8 @@ class ShardedRouter:
                            fail_rate=breaker_fail_rate,
                            min_calls=breaker_min_calls,
                            cooldown_s=breaker_cooldown_s,
-                           on_transition=self._transition_cb(i))
-            for i in range(len(self.shards))]
+                           on_transition=self._on_transition)
+            for _ in self.shards]
 
     @classmethod
     def over_devices(cls, docs, doc_ids=None, *, devices=None,
@@ -294,16 +300,25 @@ class ShardedRouter:
         self.close()
         return False
 
+    @property
+    def telemetry(self):
+        return self._telemetry
+
+    @telemetry.setter
+    def telemetry(self, telemetry) -> None:
+        self._telemetry = telemetry
+        for shard in self.shards:
+            if hasattr(shard, "telemetry"):
+                shard.telemetry = telemetry
+
     # ------------------------------------------------------------ health
-    def _transition_cb(self, shard: int) -> Callable:
-        def cb(old: str, new: str) -> None:
-            if new == "open":
-                self.stats.bump("breaker_opens")
-            elif new == "closed":
-                self.stats.bump("breaker_closes")
-            if self.telemetry is not None:
-                self.telemetry.record_breaker(shard, old, new)
-        return cb
+    def _on_transition(self, old: str, new: str) -> None:
+        if new == "open":
+            self.stats.bump("breaker_opens")
+        elif new == "closed":
+            self.stats.bump("breaker_closes")
+        if self.telemetry is not None:
+            self.telemetry.record_breaker(new)
 
     @property
     def backend_open(self) -> bool:
@@ -329,8 +344,11 @@ class ShardedRouter:
         attempt = 0
         while True:
             try:
-                ans = self.shards[i](queries, k)
-                validate_answer(ans, len(queries), k, self.n_docs)
+                with span("shard.call", shard=i, rows=len(queries),
+                          attempt=attempt):
+                    ans = self.shards[i](queries, k)
+                    with span("router.validate"):
+                        validate_answer(ans, len(queries), k, self.n_docs)
                 self.breakers[i].record(True)
                 self.stats.shard_bump(i, "calls")
                 return ans
@@ -375,71 +393,76 @@ class ShardedRouter:
         """
         self.stats.bump("calls")
         call_id = self.stats.calls
-        answers: dict[int, ShardAnswer] = {}
-        deadline = time.monotonic() + self.deadline_s
-        hedge_at = time.monotonic() + self.hedge_after_s
-        hedged: set[int] = set()
-        pending: dict[cf.Future, int] = {}
-        for i, _ in enumerate(self.shards):
-            if self.breakers[i].allow():
-                pending[self.pool.submit(
-                    self._call, i, queries, k, call_id, deadline)] = i
-            else:
-                self.stats.bump("breaker_skips")
-                self.stats.shard_bump(i, "breaker_skips")
-        if not pending:
-            self.stats.bump("shed")
-            self.stats.bump("degraded")
-            raise TimeoutError(
-                "back end fenced: every shard's circuit breaker is open")
-        while pending and (now := time.monotonic()) < deadline:
-            # wake on a completion, the hedge point, or the deadline —
-            # whichever is first (no fixed-interval busy-poll)
-            wait_s = max(min(hedge_at, deadline) - now, 0.0)
-            done, _ = cf.wait(list(pending), timeout=wait_s,
-                              return_when=cf.FIRST_COMPLETED)
-            for f in done:
-                i = pending.pop(f, None)
-                if i is None:          # sibling already discarded below
-                    continue
-                try:
-                    result = f.result()
-                except Exception:
-                    self.stats.bump("failures")
-                    continue
-                answers[i] = result
-                # drop the hedge sibling (winner merged, loser drained);
-                # only a loser that actually ran counts as duplicate work
-                for f2, i2 in list(pending.items()):
-                    if i2 == i:
-                        del pending[f2]
-                        if _discard(f2):
-                            self.stats.bump("duplicates")
-            # hedge slow shards once (closed breakers only: a half-open
-            # shard's single probe must stay single)
-            if time.monotonic() >= hedge_at:
-                for f, i in list(pending.items()):
-                    if i not in hedged and self.breakers[i].state == "closed":
-                        hedged.add(i)
-                        self.stats.bump("hedges")
-                        pending[self.pool.submit(
-                            self._call, i, queries, k, call_id, deadline)] = i
-                hedge_at = float("inf")
-        # shards still pending at the deadline are written off as
-        # timeouts — the breaker hears about them (a shard that never
-        # answers must be able to trip its breaker too)
-        for i in set(pending.values()):
-            self.stats.bump("timeouts")
-            self.stats.shard_bump(i, "timeouts")
-            self.breakers[i].record(False)
-        for f in pending:
-            _discard(f)
-        degraded = len(answers) < len(self.shards)
-        if degraded:
-            self.stats.bump("degraded")
-        if not answers:
-            raise TimeoutError("all index shards failed or timed out")
-        return self._merge(list(answers.values()), k), degraded
+        with span("router.search", call=call_id, rows=len(queries)):
+            answers: dict[int, ShardAnswer] = {}
+            deadline = time.monotonic() + self.deadline_s
+            hedge_at = time.monotonic() + self.hedge_after_s
+            hedged: set[int] = set()
+            pending: dict[cf.Future, int] = {}
+            for i, _ in enumerate(self.shards):
+                if self.breakers[i].allow():
+                    pending[self.pool.submit(
+                        self._call, i, queries, k, call_id, deadline)] = i
+                else:
+                    self.stats.bump("breaker_skips")
+                    self.stats.shard_bump(i, "breaker_skips")
+            if not pending:
+                self.stats.bump("shed")
+                self.stats.bump("degraded")
+                raise TimeoutError(
+                    "back end fenced: every shard's circuit breaker is open")
+            while pending and (now := time.monotonic()) < deadline:
+                # wake on a completion, the hedge point, or the deadline —
+                # whichever is first (no fixed-interval busy-poll)
+                wait_s = max(min(hedge_at, deadline) - now, 0.0)
+                done, _ = cf.wait(list(pending), timeout=wait_s,
+                                  return_when=cf.FIRST_COMPLETED)
+                for f in done:
+                    i = pending.pop(f, None)
+                    if i is None:          # sibling already discarded below
+                        continue
+                    try:
+                        result = f.result()
+                    except Exception:
+                        self.stats.bump("failures")
+                        continue
+                    answers[i] = result
+                    # drop the hedge sibling (winner merged, loser drained);
+                    # only a loser that actually ran counts as duplicate work
+                    for f2, i2 in list(pending.items()):
+                        if i2 == i:
+                            del pending[f2]
+                            if _discard(f2):
+                                self.stats.bump("duplicates")
+                # hedge slow shards once (closed breakers only: a half-open
+                # shard's single probe must stay single)
+                if time.monotonic() >= hedge_at:
+                    for f, i in list(pending.items()):
+                        if i not in hedged and \
+                                self.breakers[i].state == "closed":
+                            hedged.add(i)
+                            self.stats.bump("hedges")
+                            pending[self.pool.submit(
+                                self._call, i, queries, k, call_id,
+                                deadline)] = i
+                    hedge_at = float("inf")
+            # shards still pending at the deadline are written off as
+            # timeouts — the breaker hears about them (a shard that never
+            # answers must be able to trip its breaker too)
+            for i in set(pending.values()):
+                self.stats.bump("timeouts")
+                self.stats.shard_bump(i, "timeouts")
+                self.breakers[i].record(False)
+            for f in pending:
+                _discard(f)
+            degraded = len(answers) < len(self.shards)
+            if degraded:
+                self.stats.bump("degraded")
+            if not answers:
+                raise TimeoutError("all index shards failed or timed out")
+            with span("router.merge"):
+                merged = self._merge(list(answers.values()), k)
+        return merged, degraded
 
     @staticmethod
     def _merge(parts: list[ShardAnswer], k: int) -> ShardAnswer:

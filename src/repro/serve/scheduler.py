@@ -32,6 +32,14 @@ scheduler instead:
     queued turns to their own schedule instead of force-flushing the
     world.
 
+**Worker states.**  The worker thread is always in one of six spans on
+the profiler's trace (``repro.serve.telemetry.span``): ``sched.wait``
+(nothing selected, nothing in flight), ``sched.select`` (forming a wave;
+its args say how many turns were eligible, the queue depth and the
+limit), ``engine.probe`` and ``engine.fill`` (the engine's phases),
+``sched.join`` (waiting on the wave's back-end phase) and
+``sched.resolve`` (setting the waiters' futures).
+
 Migration from ``MicroBatcher``: ``MicroBatcher(fn, max_batch, window_s)``
 is ``ContinuousScheduler(fn=fn, max_wave=max_batch, window_s=window_s,
 adaptive=False, overlap=False)``; serving code should go through
@@ -45,7 +53,7 @@ import threading
 import time
 from typing import Callable, Optional
 
-from repro.serve.telemetry import ServeTelemetry
+from repro.serve.telemetry import ServeTelemetry, span
 
 __all__ = ["ContinuousScheduler"]
 
@@ -146,7 +154,7 @@ class ContinuousScheduler:
         The admission timestamp is stamped here — queue wait (admission to
         wave start) is part of the turn's measured latency."""
         item = _Item(payload, slot)
-        with self._cond:
+        with span("sched.submit"), self._cond:
             if self._closed:
                 raise RuntimeError(f"{type(self).__name__} is closed")
             self._queue.append(item)
@@ -176,7 +184,7 @@ class ContinuousScheduler:
         sessions' queued turns keep waiting on their own schedule — this
         is the per-key drain ``SessionManager.close`` uses instead of a
         global flush."""
-        with self._cond:
+        with span("sched.drain", slot=slot), self._cond:
             self._drain.add(slot)
             self._cond.notify_all()
             try:
@@ -243,25 +251,27 @@ class ContinuousScheduler:
             self.telemetry.arrivals.rate(), self._service_ewma, p99)
 
     # ---------------------------------------------------- wave selection
-    def _select_locked(self):
+    def _select_locked(self, sp):
         """Pick the next wave from the queue (caller holds the lock).
 
         Returns ``(batch, wait_s)``: a non-empty list of items removed
         from the queue, or ``(None, wait_s)`` when nothing is ready —
         ``wait_s`` is how long to sleep for a pending window hold (None =
-        until notified).
+        until notified).  A wave taken is described on ``sp``, the open
+        ``sched.select`` span.
         """
         eligible: list[_Item] = []
         seen_slots: set = set()
+        n_eligible = 0               # turns that could join, past the limit
         for it in self._queue:
             if it.slot is not None:
                 if it.slot in self._active_slots or it.slot in seen_slots:
                     seen_slots.add(it.slot)   # preserve per-slot order:
                     continue                  # later items of it stay too
                 seen_slots.add(it.slot)
-            eligible.append(it)
-            if len(eligible) >= self.wave_limit:
-                break
+            n_eligible += 1
+            if len(eligible) < self.wave_limit:
+                eligible.append(it)
         if not eligible:
             return None, None
         drain_ready = [it for it in eligible if it.slot in self._drain]
@@ -281,6 +291,14 @@ class ContinuousScheduler:
             else:
                 return None, self.window_s - age
         batch = eligible
+        args = dict(rows=len(batch), eligible=n_eligible,
+                    queue=len(self._queue), limit=self.wave_limit,
+                    max_wave=self.max_wave)
+        wave_id = getattr(self._engine, "wave_id", None)
+        if wave_id is not None:          # the id the engine will give it
+            args["wave"] = wave_id + 1
+        sp.set_metadata(**args)
+        self.telemetry.count(eligible_rows=n_eligible)
         taken = set(map(id, batch))
         self._queue = [it for it in self._queue if id(it) not in taken]
         if not drain_only:
@@ -301,13 +319,15 @@ class ContinuousScheduler:
             batch = None
             with self._cond:
                 while True:
-                    batch, wait_s = self._select_locked()
+                    with span("sched.select") as sp:
+                        batch, wait_s = self._select_locked(sp)
                     if batch is not None or inflight is not None:
                         break
                     if self._closed and not self._queue:
                         self._cond.notify_all()
                         return
-                    self._cond.wait(timeout=wait_s)
+                    with span("sched.wait"):
+                        self._cond.wait(timeout=wait_s)
             nxt = None
             if batch is not None:
                 if self._engine is None:
@@ -331,15 +351,8 @@ class ContinuousScheduler:
                     f"batch fn returned {len(results)} results for "
                     f"{len(batch)} items")
         except Exception as e:                 # noqa: BLE001
-            for it in batch:
-                it.future.set_exception(e)
-        else:
-            for it, res in zip(batch, results):
-                if isinstance(res, BaseException):
-                    it.future.set_exception(res)
-                else:
-                    it.future.set_result(res)
-        self._wave_done(batch, time.perf_counter() - t0)
+            results = e
+        self._resolve(batch, results, t0)
 
     # -------------------------------------------------- engine-mode wave
     def _begin_wave(self, batch: list) -> Optional[_Inflight]:
@@ -351,9 +364,7 @@ class ContinuousScheduler:
                 [it.slot for it in batch], [it.payload for it in batch],
                 admitted_at=[it.admitted_at for it in batch])
         except Exception as e:                 # noqa: BLE001
-            for it in batch:
-                it.future.set_exception(e)
-            self._wave_done(batch, time.perf_counter() - t0)
+            self._resolve(batch, e, t0)
             return None
         backend_future = (self._backend_pool.submit(
             self._engine.backend_wave, ws) if self.overlap else None)
@@ -364,21 +375,29 @@ class ContinuousScheduler:
         An engine exception fails this wave's futures only — the loop
         never wedges."""
         try:
-            if infl.backend_future is not None:
-                infl.backend_future.result()
-            else:
-                self._engine.backend_wave(infl.ws)
+            with span("sched.join"):
+                if infl.backend_future is not None:
+                    infl.backend_future.result()
+                else:
+                    self._engine.backend_wave(infl.ws)
             turns = self._engine.fill_wave(infl.ws)
         except Exception as e:                 # noqa: BLE001
-            for it in infl.items:
-                it.future.set_exception(e)
-        else:
-            for it, res in zip(infl.items, turns):
+            turns = e
+        self._resolve(infl.items, turns, infl.t_start)
+
+    def _resolve(self, batch: list, results, t0: float) -> None:
+        """Set each waiter's future from ``results`` (one per item; an
+        exception instance fails its own item), or fail them all with
+        ``results`` when it is one exception; then account the wave."""
+        with span("sched.resolve", rows=len(batch)):
+            if isinstance(results, BaseException):
+                results = [results] * len(batch)
+            for it, res in zip(batch, results):
                 if isinstance(res, BaseException):
                     it.future.set_exception(res)
                 else:
                     it.future.set_result(res)
-        self._wave_done(infl.items, time.perf_counter() - infl.t_start)
+            self._wave_done(batch, time.perf_counter() - t0)
 
     def _wave_done(self, batch: list, service_s: float) -> None:
         self.telemetry.record_wave(len(batch), service_s)

@@ -62,7 +62,11 @@ latency (``EngineTurn.latency_s``) with its queue wait broken out
 (``queue_wait_s``) and the wave-level probe / backend / insert spans
 attached (``spans``, a ``repro.serve.telemetry.TurnSpans``).  A wave used
 to stamp every member with the whole wave's wall clock and queue wait was
-invisible — SLO numbers were unmeasurable.
+invisible — SLO numbers were unmeasurable.  On the profiler's trace each
+phase is a span (``engine.probe`` / ``engine.backend`` / ``engine.fill``)
+carrying the wave's id (``wave_id``, also ``TurnSpans.wave``), its rows,
+its bucket and the bytes it staged on and read back from the device, with
+a child span per step; ``docs/architecture.md`` lists the tree.
 
 ``SessionManager`` puts an asynchronous front door on the engine: it maps
 external session keys to engine slots and admits ``submit``-ed turns into
@@ -95,7 +99,7 @@ from repro.kernels import dispatch as kdispatch
 from repro.serve.engine import EngineTurn
 from repro.serve.router import ShardedRouter
 from repro.serve.scheduler import ContinuousScheduler
-from repro.serve.telemetry import ServeTelemetry, TurnSpans
+from repro.serve.telemetry import ServeTelemetry, TurnSpans, span
 
 __all__ = ["BatchedEngine", "SessionManager", "WaveState"]
 
@@ -130,11 +134,14 @@ class WaveState:
     stale: np.ndarray                # (bucket,) stale-while-error memo rows
     admitted_at: np.ndarray          # (wave,) perf_counter admission stamps
     t_start: float                   # wave (probe-phase) start stamp
+    wave_id: int = 0                 # the engine's monotone wave id
     degraded: bool = False
     shed: bool = False               # back end fenced: load-shed wave
     outage: Optional[BaseException] = None
     probe_s: float = 0.0
     backend_s: float = 0.0
+    h2d_bytes: int = 0               # bytes the wave staged on the device
+    d2h_bytes: int = 0               # ... and read back, so far
 
 
 class BatchedEngine:
@@ -197,13 +204,17 @@ class BatchedEngine:
         self._shared_lock = threading.Lock()
         self.telemetry = telemetry if telemetry is not None \
             else ServeTelemetry()
+        # a router with no telemetry of its own counts into the engine's
+        # (faults, and the bytes its device shards move)
+        if getattr(router, "telemetry", False) is None:
+            router.telemetry = self.telemetry
         # validate_every: run the cache_ops.validate_state integrity check
         # over the stacked caches every N waves (0 disables) and
         # quarantine-reset any slot whose invariants are broken, instead
         # of letting a corrupted slot poison (or crash) its next wave
         self.validate_every = int(validate_every)
         self.quarantined = 0
-        self._waves = 0
+        self.wave_id = 0                 # id of the last wave probed
         self.turns: list[list[EngineTurn]] = [[] for _ in range(n_sessions)]
         # admission identity: (slot, generation) — bumped on start_session
         # so a recycled slot never inherits its predecessor's popularity
@@ -211,7 +222,8 @@ class BatchedEngine:
         self._gen = np.zeros((n_sessions,), np.int64)
 
     def start_session(self, session: int):
-        self.cache.reset([session])
+        with span("engine.reset", slot=session):
+            self.cache.reset([session])
         self.turns[session] = []
         self._prefetched[session].clear()
         self._gen[session] += 1
@@ -268,36 +280,53 @@ class BatchedEngine:
         wait for directly-invoked waves.
         """
         t_start = time.perf_counter()
-        self._waves += 1
-        if self.validate_every and self._waves % self.validate_every == 0:
-            self.quarantine_invalid()
+        self.wave_id += 1
         sids = np.asarray(sessions, np.int32)
         if np.unique(sids).size != sids.size:
             raise ValueError("one turn per session per wave")
         wave = len(sids)
         bucket = self._bucket(wave)
+        with span("engine.probe", wave=self.wave_id, rows=wave,
+                  bucket=bucket) as sp:
+            ws = self._probe(sids, queries, admitted_at, t_start, bucket)
+            sp.set_metadata(h2d_bytes=ws.h2d_bytes, d2h_bytes=ws.d2h_bytes)
+        self.telemetry.count(h2d_bytes=ws.h2d_bytes, d2h_bytes=ws.d2h_bytes,
+                             wave_rows=wave, bucket_rows=bucket)
+        ws.probe_s = time.perf_counter() - t_start
+        return ws
+
+    def _probe(self, sids, queries, admitted_at, t_start, bucket
+               ) -> WaveState:
+        if self.validate_every and self.wave_id % self.validate_every == 0:
+            self.quarantine_invalid()
+        wave = len(sids)
         admitted = (np.full((wave,), t_start, np.float64)
                     if admitted_at is None
                     else np.asarray(admitted_at, np.float64))
         # pad the wave with copies of row 0 (probe-only: do/need are forced
         # False and padded rows are never scattered back or reported)
         pad_sids = np.concatenate([sids, np.repeat(sids[:1], bucket - wave)])
-        # assembled on the host and sent once at the bucket shape, so a new
-        # wave size compiles no program of its own
-        q = np.stack([np.asarray(x) for x in queries])
-        q = jnp.asarray(np.concatenate(
-            [q, np.broadcast_to(q[:1], (bucket - wave,) + q.shape[1:])]))
-        psi = self.encoder(q) if self.encoder else q
+        with span("engine.probe.stage_queries"):
+            # assembled on the host and sent once at the bucket shape, so a
+            # new wave size compiles no program of its own
+            q = np.stack([np.asarray(x) for x in queries])
+            q = jnp.asarray(np.concatenate(
+                [q, np.broadcast_to(q[:1], (bucket - wave,) + q.shape[1:])]))
+            psi = self.encoder(q) if self.encoder else q
 
-        sub = self.cache.gather(pad_sids)
-        # launch 1: the L1 LowQuality probe over the wave's session slices
-        pr = probe_batched(sub, psi, self.epsilon, backend=self.backend,
-                           max_queries=self.cache.cfg.max_queries)
-        n_queries = np.asarray(sub.n_queries)
-        need = np.logical_or(n_queries == 0, ~np.asarray(pr.hit))
+        with span("engine.probe.l1_gather"):
+            sub = self.cache.gather(pad_sids)
+        with span("engine.probe.l1_probe"):
+            # launch 1: the L1 LowQuality probe over the wave's session
+            # slices
+            pr = probe_batched(sub, psi, self.epsilon, backend=self.backend,
+                               max_queries=self.cache.cfg.max_queries)
+            n_queries = np.asarray(sub.n_queries)
+            hit = np.asarray(pr.hit)
+            psi_np = np.asarray(psi)
+        need = np.logical_or(n_queries == 0, ~hit)
         need[wave:] = False
         tier = np.where(need, "backend", "l1").astype(object)
-        psi_np = np.asarray(psi)
 
         # rows that insert into L1 this wave fill these buffers; tier-served
         # rows (memo reuse / L2 shard hits) ride the same fused insert+query
@@ -315,7 +344,7 @@ class BatchedEngine:
         rec_np = np.zeros((bucket,), bool)
 
         if self.shared is not None:
-            with self._shared_lock:
+            with span("engine.probe.l2"), self._shared_lock:
                 self.shared.tick()
                 if need.any():
                     need = self._probe_shared(pad_sids, psi, psi_np, need,
@@ -324,7 +353,7 @@ class BatchedEngine:
             tier[reuse] = "l2_reuse"
             tier[l2hit] = "l2"
 
-        ws = WaveState(
+        return WaveState(
             sids=sids, pad_sids=pad_sids, wave=wave, bucket=bucket,
             psi=psi, psi_np=psi_np, sub=sub, need=need, tier=tier,
             reuse=reuse, l2hit=l2hit, new_ids=new_ids, new_emb=new_emb,
@@ -332,9 +361,10 @@ class BatchedEngine:
             backend_ok=np.zeros((bucket,), bool),
             failed=np.zeros((bucket,), bool),
             stale=np.zeros((bucket,), bool),
-            admitted_at=admitted, t_start=t_start)
-        ws.probe_s = time.perf_counter() - t_start
-        return ws
+            admitted_at=admitted, t_start=t_start, wave_id=self.wave_id,
+            # the staged queries and slot row; the probe's reads
+            h2d_bytes=q.nbytes + pad_sids.nbytes,
+            d2h_bytes=n_queries.nbytes + hit.nbytes + psi_np.nbytes)
 
     def _probe_shared(self, pad_sids, psi, psi_np, need, reuse, l2hit,
                       new_ids, new_emb, rad, rec_np) -> np.ndarray:
@@ -407,68 +437,83 @@ class BatchedEngine:
         (jaxpr-guarded in tests).
         """
         t0 = time.perf_counter()
+        moved = (ws.h2d_bytes, ws.d2h_bytes)
+        with span("engine.backend", wave=ws.wave_id, rows=ws.wave,
+                  bucket=ws.bucket) as sp:
+            try:
+                return self._backend(ws)
+            finally:
+                ws.backend_s = time.perf_counter() - t0
+                h2d = ws.h2d_bytes - moved[0]
+                d2h = ws.d2h_bytes - moved[1]
+                sp.set_metadata(h2d_bytes=h2d, d2h_bytes=d2h)
+                self.telemetry.count(h2d_bytes=h2d, d2h_bytes=d2h)
+
+    def _backend(self, ws: WaveState) -> WaveState:
         need, bucket, wave = ws.need, ws.bucket, ws.wave
-        try:
-            if need.any():
-                if getattr(self.router, "backend_open", False):
-                    ws.shed = True
-                    self.telemetry.record_fault("shed_waves")
-                    self.telemetry.record_fault(
-                        "shed_turns", int(need[:wave].sum()))
-                    self._outage_fallback(ws, TimeoutError(
-                        "back end fenced: load-shed wave"))
-                    if ws.failed[:wave].all():
-                        raise ws.outage
-                    return ws
-                miss = np.nonzero(need)[0]
-                try:
-                    ans, degraded = self.router.search(
-                        ws.psi_np[miss], self.k_c)
-                    ws.degraded = degraded
-                    n_valid = (ans.ids >= 0).sum(axis=1)
-                    if (n_valid == 0).any():
-                        raise TimeoutError(
-                            "back-end answer holds no valid docs")
-                    # r_a per row from the last *valid* column (short
-                    # merges are sentinel-padded); same guard as the
-                    # sequential engine.  Computed at the bucket length,
-                    # so each bucket compiles it once, not each miss count
+        if need.any():
+            if getattr(self.router, "backend_open", False):
+                ws.shed = True
+                self.telemetry.record_fault("shed_waves")
+                self.telemetry.record_fault(
+                    "shed_turns", int(need[:wave].sum()))
+                self._outage_fallback(ws, TimeoutError(
+                    "back end fenced: load-shed wave"))
+                if ws.failed[:wave].all():
+                    raise ws.outage
+                return ws
+            miss = np.nonzero(need)[0]
+            try:
+                ans, degraded = self.router.search(
+                    ws.psi_np[miss], self.k_c)
+                ws.degraded = degraded
+                n_valid = (ans.ids >= 0).sum(axis=1)
+                if (n_valid == 0).any():
+                    raise TimeoutError(
+                        "back-end answer holds no valid docs")
+                # r_a per row from the last *valid* column (short
+                # merges are sentinel-padded); same guard as the
+                # sequential engine.  Computed at the bucket length,
+                # so each bucket compiles it once, not each miss count
+                with span("engine.backend.radii"):
                     last = np.take_along_axis(
                         ans.scores, n_valid[:, None] - 1, axis=1)[:, 0]
-                    radii = np.asarray(distance_from_scores(jnp.asarray(
-                        np.pad(last, (0, bucket - len(last))))))[:len(last)]
+                    staged = jnp.asarray(np.pad(last, (0, bucket - len(last))))
+                    back = np.asarray(distance_from_scores(staged))
+                    radii = back[:len(last)]
+                ws.h2d_bytes += staged.nbytes
+                ws.d2h_bytes += back.nbytes
+                with span("engine.backend.gather_rows"):
                     ws.new_ids[miss, :self.k_c] = ans.ids
                     ws.new_emb[miss, :self.k_c] = self.doc_embeddings[
                         np.maximum(ans.ids, 0)]
-                    ws.rad[miss] = radii
-                    # a degraded merge is missing shards: keep the docs,
-                    # skip the (psi, r_a) record so no cache learns a
-                    # false claim
-                    ws.rec_np[miss] = not degraded
-                    ws.backend_ok = need.copy()
-                    if self.shared is not None and not degraded:
-                        # fresh retrievals feed the shared tier: memoized
-                        # for semantic reuse, offered toward admission
-                        with self._shared_lock:
-                            for j, i in enumerate(miss):
-                                tok = self._token(ws.pad_sids[i])
-                                self.shared.memo_record(
-                                    tok, ws.psi_np[i], ans.ids[j],
-                                    ans.scores[j], float(radii[j]))
-                                self.shared.offer(
-                                    tok, ws.psi_np[i], float(radii[j]),
-                                    ws.new_emb[i], ws.new_ids[i])
-                except TimeoutError as e:
-                    # total back-end failure: miss sessions fall back to
-                    # their caches (or the stale memo); one with neither
-                    # fails alone, like its sequential counterpart — not
-                    # the whole wave
-                    self._outage_fallback(ws, e)
-                    if ws.failed[:wave].all():
-                        raise
-            return ws
-        finally:
-            ws.backend_s = time.perf_counter() - t0
+                ws.rad[miss] = radii
+                # a degraded merge is missing shards: keep the docs,
+                # skip the (psi, r_a) record so no cache learns a
+                # false claim
+                ws.rec_np[miss] = not degraded
+                ws.backend_ok = need.copy()
+                if self.shared is not None and not degraded:
+                    # fresh retrievals feed the shared tier: memoized
+                    # for semantic reuse, offered toward admission
+                    with span("engine.backend.l2_record"), self._shared_lock:
+                        for j, i in enumerate(miss):
+                            tok = self._token(ws.pad_sids[i])
+                            self.shared.memo_record(
+                                tok, ws.psi_np[i], ans.ids[j],
+                                ans.scores[j], float(radii[j]))
+                            self.shared.offer(
+                                tok, ws.psi_np[i], float(radii[j]),
+                                ws.new_emb[i], ws.new_ids[i])
+            except TimeoutError as e:
+                # total back-end failure: miss sessions fall back to
+                # their caches (or the stale memo); one with neither
+                # fails alone, like its sequential counterpart — not
+                # the whole wave
+                self._outage_fallback(ws, e)
+                if ws.failed[:wave].all():
+                    raise
+        return ws
 
     def _outage_fallback(self, ws: WaveState, e: BaseException) -> None:
         """Walk the degradation ladder for a wave whose back-end search
@@ -508,6 +553,17 @@ class BatchedEngine:
         for a session whose back-end failed entirely while its cache was
         still empty.
         """
+        moved = (ws.h2d_bytes, ws.d2h_bytes)
+        with span("engine.fill", wave=ws.wave_id, rows=ws.wave,
+                  bucket=ws.bucket) as sp:
+            out = self._fill(ws)
+            h2d = ws.h2d_bytes - moved[0]
+            d2h = ws.d2h_bytes - moved[1]
+            sp.set_metadata(h2d_bytes=h2d, d2h_bytes=d2h)
+        self.telemetry.count(h2d_bytes=h2d, d2h_bytes=d2h)
+        return out
+
+    def _fill(self, ws: WaveState) -> list:
         t0 = time.perf_counter()
         if self.prefetch_width and self.cluster is not None:
             # Topical prefetch: expand each fresh back-end answer with its
@@ -518,36 +574,45 @@ class BatchedEngine:
             # the recorded claim widens to max(r_a, d_w - ||psi - c||).
             # (Like the r_a claim itself, this assumes capacity headroom —
             # dropped inserts void claims; size L1 >= k_c + width.)
-            for i in np.nonzero(ws.backend_ok)[0]:
-                extra, bound = self.cluster.prefetch(
-                    ws.psi_np[i], ws.new_ids[i, :self.k_c],
-                    self.prefetch_width)
-                if extra.size:
-                    ws.new_ids[i, self.k_c:self.k_c + extra.size] = extra
-                    ws.new_emb[i, self.k_c:self.k_c + extra.size] = \
-                        self.doc_embeddings[extra]
-                    self.prefetch_issued += int(extra.size)
-                    self._prefetched[int(ws.pad_sids[i])].update(
-                        extra.tolist())
-                if ws.rec_np[i] and bound > ws.rad[i]:
-                    ws.rad[i] = bound
+            with span("engine.fill.prefetch"):
+                for i in np.nonzero(ws.backend_ok)[0]:
+                    extra, bound = self.cluster.prefetch(
+                        ws.psi_np[i], ws.new_ids[i, :self.k_c],
+                        self.prefetch_width)
+                    if extra.size:
+                        cols = slice(self.k_c, self.k_c + extra.size)
+                        ws.new_ids[i, cols] = extra
+                        ws.new_emb[i, cols] = self.doc_embeddings[extra]
+                        self.prefetch_issued += int(extra.size)
+                        self._prefetched[int(ws.pad_sids[i])].update(
+                            extra.tolist())
+                    if ws.rec_np[i] and bound > ws.rad[i]:
+                        ws.rad[i] = bound
         fill = np.logical_or(np.logical_or(ws.reuse, ws.l2hit),
                              ws.backend_ok)
         if fill.any():
             self.insert_traffic_docs += int((ws.new_ids[fill] >= 0).sum())
-            # insert + answer query FUSED: one kernel launch closes the
-            # wave (L1-only: launch 3 of 3, probe -> miss-search ->
-            # insert+query; tiered: launch 4 of 4, after the L2 probe)
-            (scores, _dists, ids, _slots), sub, dropped = \
-                insert_query_batched(
-                    ws.sub, self.cache.cfg, ws.psi, jnp.asarray(ws.rad),
-                    jnp.asarray(ws.new_emb), jnp.asarray(ws.new_ids),
-                    self.k, do=jnp.asarray(fill),
-                    record=jnp.asarray(ws.rec_np), backend=self.backend)
-            self.cache.total_dropped += int(np.asarray(dropped).sum())
+            with span("engine.fill.stage_inserts"):
+                staged = [jnp.asarray(x) for x in (
+                    ws.rad, ws.new_emb, ws.new_ids, fill, ws.rec_np)]
+            ws.h2d_bytes += sum(x.nbytes for x in staged)
+            rad, new_emb, new_ids, do, record = staged
+            with span("engine.fill.insert_query"):
+                # insert + answer query FUSED: one kernel launch closes the
+                # wave (L1-only: launch 3 of 3, probe -> miss-search ->
+                # insert+query; tiered: launch 4 of 4, after the L2 probe)
+                (scores, _dists, ids, _slots), sub, dropped = \
+                    insert_query_batched(
+                        ws.sub, self.cache.cfg, ws.psi, rad, new_emb,
+                        new_ids, self.k, do=do, record=record,
+                        backend=self.backend)
+                dropped = np.asarray(dropped)
+            ws.d2h_bytes += dropped.nbytes
+            self.cache.total_dropped += int(dropped.sum())
         else:  # missless (or outage) wave: probe -> query
-            (scores, _dists, ids, _slots), sub = query_batched(
-                ws.sub, ws.psi, self.k, backend=self.backend)
+            with span("engine.fill.query"):
+                (scores, _dists, ids, _slots), sub = query_batched(
+                    ws.sub, ws.psi, self.k, backend=self.backend)
         # the launch consumed the gathered rows: release them before the
         # write-back, which holds old and new stacked state at once
         ws.sub = None
@@ -558,54 +623,60 @@ class BatchedEngine:
         rows = np.full((ws.bucket,), self.n_sessions, np.int32)
         able = np.nonzero(~ws.failed[:ws.wave])[0]
         rows[able] = ws.sids[able]
-        self.cache.scatter(rows, sub)
+        with span("engine.fill.scatter"):
+            self.cache.scatter(rows, sub)
+        ws.h2d_bytes += rows.nbytes
         if self.shared is not None:
             # end-of-wave: promote the wave's admitted answers into their
             # shards (deferred so admission never adds launches mid-wave)
-            with self._shared_lock:
+            with span("engine.fill.l2_flush"), self._shared_lock:
                 self.shared.flush_admissions(backend=self.backend)
 
         resolved = time.perf_counter()
         insert_s = resolved - t0
         out: list = []
-        for i, s in enumerate(ws.sids):
-            if ws.failed[i]:
-                self.telemetry.record_fault("failed_turns")
-                out.append(TimeoutError(
-                    f"session {int(s)}: back-end down and cache empty"
-                    f" ({ws.outage})"))
-                continue
-            # drop (id -1, score -inf) sentinel slots of a short cache, the
-            # same trim the sequential engine applies
-            row_ids = np.asarray(ids[i])
-            row_scores = np.asarray(scores[i])
-            real = row_ids >= 0
-            row_tier = str(ws.tier[i])
-            pre = self._prefetched[int(s)]
-            n_pre = (sum(1 for d in row_ids[real].tolist() if d in pre)
-                     if pre else 0)
-            if n_pre and row_tier != "backend":
-                self.prefetch_warm_hits += n_pre
-            spans = TurnSpans(
-                queue_wait_s=max(ws.t_start - float(ws.admitted_at[i]), 0.0),
-                probe_s=ws.probe_s, backend_s=ws.backend_s,
-                insert_s=insert_s,
-                total_s=resolved - float(ws.admitted_at[i]), tier=row_tier)
-            # a degraded wave degrades its backend-tier rows AND any row
-            # served stale-while-error (fresh tier hits stay first-class)
-            turn = EngineTurn(ids=row_ids[real], scores=row_scores[real],
-                              hit=row_tier != "backend",
-                              degraded=bool(ws.degraded
-                                            and (row_tier == "backend"
-                                                 or ws.stale[i])),
-                              latency_s=spans.total_s, tier=row_tier,
-                              queue_wait_s=spans.queue_wait_s, spans=spans,
-                              prefetch_hits=n_pre)
-            if turn.degraded:
-                self.telemetry.record_fault("degraded_turns")
-            self.telemetry.record_turn(spans)
-            self.turns[int(s)].append(turn)
-            out.append(turn)
+        with span("engine.fill.assemble"):
+            for i, s in enumerate(ws.sids):
+                if ws.failed[i]:
+                    self.telemetry.record_fault("failed_turns")
+                    out.append(TimeoutError(
+                        f"session {int(s)}: back-end down and cache empty"
+                        f" ({ws.outage})"))
+                    continue
+                # drop (id -1, score -inf) sentinel slots of a short cache,
+                # the same trim the sequential engine applies
+                row_ids = np.asarray(ids[i])
+                row_scores = np.asarray(scores[i])
+                ws.d2h_bytes += row_ids.nbytes + row_scores.nbytes
+                real = row_ids >= 0
+                row_tier = str(ws.tier[i])
+                pre = self._prefetched[int(s)]
+                n_pre = (sum(1 for d in row_ids[real].tolist() if d in pre)
+                         if pre else 0)
+                if n_pre and row_tier != "backend":
+                    self.prefetch_warm_hits += n_pre
+                admitted = float(ws.admitted_at[i])
+                spans = TurnSpans(
+                    queue_wait_s=max(ws.t_start - admitted, 0.0),
+                    probe_s=ws.probe_s, backend_s=ws.backend_s,
+                    insert_s=insert_s, total_s=resolved - admitted,
+                    tier=row_tier, wave=ws.wave_id)
+                # a degraded wave degrades its backend-tier rows AND any
+                # row served stale-while-error (fresh tier hits stay
+                # first-class)
+                turn = EngineTurn(
+                    ids=row_ids[real], scores=row_scores[real],
+                    hit=row_tier != "backend",
+                    degraded=bool(ws.degraded and (row_tier == "backend"
+                                                   or ws.stale[i])),
+                    latency_s=spans.total_s, tier=row_tier,
+                    queue_wait_s=spans.queue_wait_s, spans=spans,
+                    prefetch_hits=n_pre)
+                if turn.degraded:
+                    self.telemetry.record_fault("degraded_turns")
+                self.telemetry.record_turn(spans)
+                self.turns[int(s)].append(turn)
+                out.append(turn)
         return out
 
     def answer_batch(self, sessions, queries) -> list:
@@ -704,14 +775,15 @@ class SessionManager:
 
     def open(self, key) -> int:
         """Start a session for ``key``; returns its engine slot."""
-        if key in self._slots:
-            raise KeyError(f"session {key!r} already open")
-        if not self._free:
-            raise RuntimeError("no free session slots")
-        slot = self._free.pop()
-        self.engine.start_session(slot)
-        self._slots[key] = slot
-        return slot
+        with span("session.open"):
+            if key in self._slots:
+                raise KeyError(f"session {key!r} already open")
+            if not self._free:
+                raise RuntimeError("no free session slots")
+            slot = self._free.pop()
+            self.engine.start_session(slot)
+            self._slots[key] = slot
+            return slot
 
     def close(self, key):
         """End a session and recycle its slot, draining only THIS key's
@@ -719,10 +791,11 @@ class SessionManager:
         execute against the slot's next occupant).  Other sessions'
         queued and in-flight turns are untouched — closing a session no
         longer force-flushes the global wave."""
-        if key not in self._slots:
-            raise KeyError(f"unknown session key {key!r}")
-        self.scheduler.drain_slot(self._slots[key])
-        self._free.append(self._slots.pop(key))
+        with span("session.close"):
+            if key not in self._slots:
+                raise KeyError(f"unknown session key {key!r}")
+            self.scheduler.drain_slot(self._slots[key])
+            self._free.append(self._slots.pop(key))
 
     def shutdown(self):
         """Drain pending turns and stop the scheduler's worker thread.

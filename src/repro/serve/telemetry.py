@@ -23,25 +23,69 @@ open-loop harness share:
   * ``ServeTelemetry`` — the aggregate the engine/scheduler write into:
     one ring per span kind, one ring of totals per serving tier
     (l1 / l2 / l2_reuse / backend), wave-size and wave-service histories,
-    and a ``summary()`` that flattens to the p50/p95/p99 columns
-    ``BENCH_serve.json`` commits and ``check_regression.py`` gates.
+    monotone counters (host<->device bytes, wave and bucket rows, eligible
+    rows, gc pauses), and a ``summary()`` that flattens to the p50/p95/p99
+    columns ``BENCH_serve.json`` commits and ``check_regression.py`` gates.
+  * ``span(name, **args)`` — a ``jax.profiler.TraceAnnotation`` named
+    ``repro.<name>``: a host span on the profiler's own trace, on the clock
+    of the device's ops, with ``args`` as its event stats.  The serving
+    path opens one around each scheduler state, wave phase, router and
+    shard step (``docs/architecture.md`` lists the tree).  A span records
+    only while a profile is being taken; otherwise it costs about a
+    microsecond and writes nothing.  ``host.gc`` spans mark the garbage
+    collector's pauses on the thread they stop.
 
-Everything here is plain host-side Python — no jax imports — so recording
-a span never touches the device or the trace cache.
+Nothing here touches the device: recording a span or a counter adds no
+transfer, no synchronisation and no launch.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import gc
 import math
 import threading
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["TurnSpans", "RingPercentiles", "EwmaRate", "ServeTelemetry",
-           "TIERS"]
+           "TIERS", "span"]
 
 TIERS = ("l1", "l2", "l2_reuse", "backend")
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    """Host span ``repro.<name>`` on the profiler's trace; ``args`` (ints
+    or strings) become its event stats, and ``set_metadata`` on the open
+    span adds more.  Use as a context manager."""
+    return TraceAnnotation("repro." + name, **args)
+
+
+_gc_pauses = 0                   # collections ever started in the process
+_gc_span: Optional[TraceAnnotation] = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``host.gc`` span per collection, opened
+    and closed on the collecting thread."""
+    global _gc_pauses, _gc_span
+    if phase == "start":
+        _gc_pauses += 1
+        _gc_span = span("host.gc", generation=info["generation"])
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        _gc_span.set_metadata(collected=info["collected"])
+        _gc_span.__exit__(None, None, None)
+        _gc_span = None
+
+
+def _watch_gc() -> int:
+    """Install the ``host.gc`` hook once; returns the pause count now."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    return _gc_pauses
 
 
 @dataclasses.dataclass
@@ -59,6 +103,7 @@ class TurnSpans:
     insert_s: float = 0.0
     total_s: float = 0.0
     tier: str = "backend"
+    wave: int = 0          # the engine's wave id: its spans' ``wave`` arg
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -182,7 +227,18 @@ class ServeTelemetry:
     and per-wave (size, service seconds) samples.  Readers: the
     scheduler's sizing policy (``arrivals.rate()``, ``wave_service``),
     ``serve_bench``'s open-loop harness, and operators via ``summary()``.
+
+    Counters (``COUNTERS``, monotone ints, always on): ``h2d_bytes`` /
+    ``d2h_bytes`` — the ``nbytes`` of every array a wave stages on or
+    reads back from the device (engine phases and device shards);
+    ``wave_rows`` / ``bucket_rows`` — real and padded rows of the waves
+    probed; ``eligible_rows`` — turns that could have joined each wave
+    the scheduler took; ``gc_pauses`` — garbage collections since this
+    object was made.  The spans carry the same numbers per wave.
     """
+
+    COUNTERS = ("h2d_bytes", "d2h_bytes", "wave_rows", "bucket_rows",
+                "eligible_rows", "gc_pauses")
 
     SPAN_KEYS = ("queue_wait_s", "probe_s", "backend_s", "insert_s",
                  "total_s")
@@ -199,9 +255,12 @@ class ServeTelemetry:
         # rejected-answer / stale-served / quarantined events) — written
         # by the router and engine, read by serve_bench --chaos
         self.faults: dict = {}
-        self.breaker_log: list = []      # (shard, old_state, new_state)
-        self.breaker_transitions = 0     # monotone (the log is bounded)
+        self.breaker_transitions = 0     # monotone
         self._fault_lock = threading.Lock()
+        self.h2d_bytes = self.d2h_bytes = 0
+        self.wave_rows = self.bucket_rows = self.eligible_rows = 0
+        self._count_lock = threading.Lock()
+        self._gc0 = _watch_gc()
 
     # ------------------------------------------------------------ writers
     def record_arrival(self, t: Optional[float] = None) -> None:
@@ -214,14 +273,24 @@ class ServeTelemetry:
         with self._fault_lock:
             self.faults[kind] = self.faults.get(kind, 0) + n
 
-    def record_breaker(self, shard: int, old: str, new: str) -> None:
-        """Log one circuit-breaker transition (bounded log + counters)."""
+    def record_breaker(self, new: str) -> None:
+        """Count one circuit-breaker transition (``faults["breaker_<new>"]``
+        and the total)."""
         with self._fault_lock:
             self.breaker_transitions += 1
             self.faults[f"breaker_{new}"] = \
                 self.faults.get(f"breaker_{new}", 0) + 1
-            if len(self.breaker_log) < 1024:
-                self.breaker_log.append((shard, old, new))
+
+    def count(self, **n: int) -> None:
+        """Add to counters by name (``h2d_bytes=...``, ``bucket_rows=...``);
+        safe from any thread."""
+        with self._count_lock:
+            for k, v in n.items():
+                setattr(self, k, getattr(self, k) + int(v))
+
+    @property
+    def gc_pauses(self) -> int:
+        return _gc_pauses - self._gc0
 
     def record_turn(self, spans: TurnSpans) -> None:
         self.turns += 1
@@ -255,4 +324,5 @@ class ServeTelemetry:
             "wave_service_s": self.wave_service.summary(),
             "faults": faults,
             "breaker_transitions": transitions,
+            "counters": {k: getattr(self, k) for k in self.COUNTERS},
         }

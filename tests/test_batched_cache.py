@@ -131,3 +131,52 @@ def test_gather_scatter_roundtrip_leaves_others_alone():
         np.testing.assert_array_equal(a[1], b[1], err_msg=name)
         np.testing.assert_array_equal(a[3], b[3], err_msg=name)
     assert after.step[0] == before.step[0] + 1     # step leaf
+
+
+def test_reset_and_scatter_from_two_threads_lose_no_write():
+    """Sessions open (``reset``) on callers' threads while the serving
+    worker writes waves back (``scatter``) into the same stacked state.
+    Each is a read-modify-write of the whole state: a reset that lands
+    inside a write-back must not be undone by it."""
+    import sys
+    import threading
+    import time
+
+    cfg = C.CacheConfig(capacity=16, dim=DIM)
+    cache = C.BatchedMetricCache(cfg, 8)
+    mark = lambda v: jax.tree_util.tree_map(     # noqa: E731
+        lambda x: jnp.full((1,) + x.shape[1:], v, x.dtype), cache.gather([0]))
+    dirty, clean = mark(7), mark(0)
+    for s in range(1, 5):                        # compile before racing
+        cache.scatter(np.array([s], np.int32), dirty)
+        cache.reset([s])
+    lost = []
+
+    def writer(stop):             # the worker: waves into slot 0 only
+        while time.perf_counter() < stop:
+            cache.scatter(np.array([0], np.int32), clean)
+            jax.block_until_ready(cache.state)
+
+    def opener(slot, stop):       # a caller: dirty its slot, then reset
+        while time.perf_counter() < stop:
+            cache.scatter(np.array([slot], np.int32), dirty)
+            cache.reset([slot])
+            if int(np.asarray(cache.state.doc_ids[slot, 0])) == 7:
+                lost.append(slot)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        stop = time.perf_counter() + 3.0
+        threads = [threading.Thread(target=writer, args=(stop,))
+                   for _ in range(2)] + \
+            [threading.Thread(target=opener, args=(s, stop))
+             for s in range(1, 5)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(was)
+    assert not lost, f"{len(lost)} resets undone by a concurrent write-back"

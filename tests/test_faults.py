@@ -375,8 +375,8 @@ def test_engine_shed_then_breaker_recovery(index, docs):
     router.breakers = [
         CircuitBreaker(window=4, fail_rate=0.5, min_calls=2,
                        cooldown_s=10.0, clock=lambda: t[0],
-                       on_transition=router._transition_cb(i))
-        for i in range(len(router.shards))]
+                       on_transition=router._on_transition)
+        for _ in router.shards]
     with router:
         for s in (0, 1):
             eng.start_session(s)
