@@ -6,8 +6,8 @@ is re-thought for the TPU memory hierarchy:
   * stream the corpus through VMEM one (TILE_N, D) tile at a time,
   * scores = Q @ tile.T on the MXU (D is zero-padded to a lane multiple by
     the wrapper, which leaves inner products unchanged),
-  * top-k extraction by iterative max-extract on the VPU, so the full (B, N)
-    score matrix is NEVER materialized in HBM.
+  * a running top-k kept on chip, so the full (B, N) score matrix is NEVER
+    materialized in HBM.
 
 Two merge strategies:
 
@@ -17,10 +17,15 @@ Two merge strategies:
     ``make_async_copy`` HBM->VMEM transfers into two scratch slots — tile
     t+1's ``(docs, ids, scale)`` copy is launched *before* tile t is
     scored, so data movement overlaps the MXU/VPU work instead of
-    serializing with it.  The running global top-k is a (B, k) carry held
-    in VMEM scratch across tiles: each tile's scores are merged against
-    the carry in-register and only the final (B, k) answer is ever written
-    to HBM.  The corpus is read exactly once and the candidate traffic of
+    serializing with it.  The running global top-k is an *unsorted* (B, k)
+    carry held in VMEM scratch across tiles.  A tile merges only the
+    scores that beat its row's current k-th best: the tile's candidate
+    count above that threshold (the max over rows) bounds a loop that
+    moves the best remaining tile score into the carry's worst lane, so a
+    late tile whose scores all lose costs no merge step at all.  The carry
+    is ordered best first once, at the end, and only the final (B, k)
+    answer is ever written to HBM; a third output counts the merge steps
+    run.  The corpus is read exactly once and the candidate traffic of
     the two-stage scheme (O(tiles * B * k) rows through HBM plus a second
     launch to merge) disappears entirely.  A ``pl.CostEstimate`` sized
     from the quant-aware byte counts tells the scheduler the launch is
@@ -29,7 +34,8 @@ Two merge strategies:
     and device-sharded corpora, and extracted -inf candidates report id
     -1, never a clipped real id.
   * ``knn_tile_topk`` — the original two-stage scheme (per-tile top-k
-    candidates to HBM, cross-tile ``lax.top_k`` merge in the wrapper), kept
+    candidates by iterative max-extract on the VPU, written to HBM;
+    cross-tile ``lax.top_k`` merge in the wrapper), kept
     as the A/B baseline for ``kernel_bench`` and for the k > tile_n regime.
     Its tile stream rides the grid pipeline (which Mosaic double-buffers
     automatically) with the same ``pl.CostEstimate`` hints attached.
@@ -65,6 +71,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
+INT_MIN = int(jnp.iinfo(jnp.int32).min)
+INT_MAX = int(jnp.iinfo(jnp.int32).max)
 LANE = 128
 # f32 dots run at full f32 precision on every tier (the MXU's default would
 # round f32 operands to bf16), so kernel and ref rankings agree on the chip
@@ -86,7 +94,8 @@ def extract_topk(pool_v, pool_ids, out_v, out_ids, *, k: int, knock: float,
     ``pool_v`` (B, P) holds the values and ``pool_ids`` a tuple of int32
     (B, P) payloads riding with them (ids, slots); ``out_v`` / ``out_ids``
     are the matching (B, Kp) carries.  Iterative max-extract on the VPU;
-    argmax's first match breaks ties by pool position.  Each step writes
+    argmax picks among equal values (the first in the interpreter, not
+    always the first on the chip).  Each step writes
     its column with a full-width ``jnp.where`` (lane-aligned, unlike a
     one-column store), then knocks the winner out of the pool with
     ``knock``.  ``empty_id`` (when given) replaces the payloads of an
@@ -146,26 +155,91 @@ def _masked_scores(q, docs, ids, scale, q_scale=None, *, int8_dot=False):
     return jnp.where(ids < 0, NEG_INF, scores)
 
 
-def _merge_tile_into_carry(scores, ids, carry_v, carry_i, *, k: int):
-    """Merge one tile's (B, TILE_N) scores into the (B, Kp) VMEM carry.
+def _merge_tile_into_carry(scores, ids, base, pool, carry_v, carry_i,
+                           carry_p, *, k: int):
+    """Merge one tile's (B, TILE_N) scores into the unsorted (B, Kp) carry.
 
-    candidate pool = running carry ++ this tile; carry columns come first,
-    so equal scores resolve to the earliest corpus position — the same
-    tie-break a stable global lax.top_k applies.  Carry columns past ``k``
-    (lane padding) stay -inf / -1.
+    The carry holds each row's k best ``(score, id)`` seen so far in no
+    order, with ``carry_p`` the corpus position of each (``base`` is the
+    tile's first position).  Lanes k..Kp-1 are lane padding: they never
+    take a candidate and never count as the row's worst entry.  Only a
+    score strictly above the row's worst carry entry can enter, so the
+    loop runs ``min(k, max over rows of count(score > worst))`` steps —
+    none at all for a tile whose scores all lose.  Each step takes every
+    row's best remaining tile score from ``pool`` (of equal ones, the
+    earliest) and, where it beats the row's worst entry, writes it over
+    that entry; among equal worst entries the one at the latest position
+    goes.  The carry therefore always holds the k best by (score
+    descending, position ascending) — the stable ``lax.top_k`` rule of the
+    ref tier.  Returns the number of steps run.
     """
-    cand_v = jnp.concatenate([carry_v[...], scores], axis=1)
-    cand_i = jnp.concatenate(
-        [carry_i[...], jnp.broadcast_to(ids, scores.shape)], axis=1)
-    carry_v[...], (carry_i[...],) = extract_topk(
-        cand_v, (cand_i,), carry_v[...], (carry_i[...],), k=k,
-        knock=NEG_INF, empty_id=-1)
+    live = jax.lax.broadcasted_iota(jnp.int32, (1, carry_v.shape[1]), 1) < k
+    col = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
+
+    def worst():
+        return jnp.min(jnp.where(live, carry_v[...], jnp.inf), axis=1,
+                       keepdims=True)
+
+    pool[...] = scores
+    above = jnp.sum((scores > worst()).astype(jnp.int32), axis=1,
+                    keepdims=True)
+    steps = jnp.minimum(jnp.max(above), k)
+
+    def step(_, unused):
+        s = pool[...]
+        best = jnp.max(s, axis=1, keepdims=True)                # (B, 1)
+        # the first column holding it: argmax breaks ties by column in the
+        # interpreter but not on the chip
+        a = jnp.min(jnp.where(s == best, col, s.shape[1]), axis=1,
+                    keepdims=True)                              # (B, 1)
+        hit = col == a
+        pool[...] = jnp.where(hit, NEG_INF, s)
+        w = worst()
+        cv, cp = carry_v[...], carry_p[...]
+        evict = jnp.max(jnp.where(live & (cv == w), cp, INT_MIN), axis=1,
+                        keepdims=True)
+        put = (best > w) & (cp == evict)
+        # one-hot reduce instead of a gather: the id at the taken column
+        new_id = jnp.sum(jnp.where(hit, ids, 0), axis=1, keepdims=True)
+        carry_v[...] = jnp.where(put, best, cv)
+        carry_i[...] = jnp.where(put, new_id, carry_i[...])
+        carry_p[...] = jnp.where(put, base + a, cp)
+        return unused
+
+    jax.lax.fori_loop(0, steps, step, 0)
+    return steps
+
+
+def _order_carry(cv, ci, cp, out_v, out_i, *, k: int):
+    """Write the carry's k best, best first, into columns 0..k-1 of the
+    (B, Kp) outputs; equal scores go in corpus-position order.
+
+    Positions are distinct in every lane (empty lanes hold negative
+    ones), so each step takes exactly one lane.  Empty lanes hold -inf
+    with id -1, which is what a row with fewer than k valid documents
+    reports past its last one.
+    """
+    col = jax.lax.broadcasted_iota(jnp.int32, cv.shape, 1)
+
+    def extract(j, state):
+        v, p, ov, oi = state
+        m = jnp.max(v, axis=1, keepdims=True)
+        first = jnp.min(jnp.where(v == m, p, INT_MAX), axis=1, keepdims=True)
+        hit = p == first
+        picked = jnp.sum(jnp.where(hit, ci, 0), axis=1, keepdims=True)
+        put = col == j
+        return (jnp.where(hit, NEG_INF, v), jnp.where(hit, INT_MAX, p),
+                jnp.where(put, m, ov), jnp.where(put, picked, oi))
+
+    _, _, out_v[...], out_i[...] = jax.lax.fori_loop(
+        0, k, extract, (cv, cp, jnp.full(cv.shape, NEG_INF, jnp.float32),
+                        jnp.full(ci.shape, -1, jnp.int32)))
 
 
 def _fused_kernel(q_ref, qscale_ref, docs_hbm, ids_hbm, scale_hbm,
-                  out_vals_ref, out_idx_ref,
-                  docs_buf, ids_buf, scale_buf, carry_v, carry_i,
-                  docs_sem, ids_sem, scale_sem,
+                  out_vals_ref, out_idx_ref, out_steps_ref,
+                  docs_buf, ids_buf, scale_buf, pool, carry_v, carry_i,
+                  carry_p, docs_sem, ids_sem, scale_sem,
                   *, k: int, tile_n: int, tiles: int, int8_dot: bool):
     """Single launch: double-buffered HBM->VMEM tile pipeline + on-chip merge.
 
@@ -173,10 +247,14 @@ def _fused_kernel(q_ref, qscale_ref, docs_hbm, ids_hbm, scale_hbm,
     scratch slots per operand hold the in-flight and the in-use tile.  Tile
     t+1's three DMAs start before tile t is scored, so the MXU never waits
     on HBM except for the very first tile (and the autotuner budgets VMEM
-    for exactly these two resident tiles).
+    for exactly these two resident tiles).  ``out_steps_ref`` (SMEM)
+    receives the merge steps run over all tiles.
     """
     carry_v[...] = jnp.full(carry_v.shape, NEG_INF, jnp.float32)
     carry_i[...] = jnp.full(carry_i.shape, -1, jnp.int32)
+    # distinct negative positions for the empty lanes
+    carry_p[...] = -1 - jax.lax.broadcasted_iota(jnp.int32, carry_p.shape, 1)
+    out_steps_ref[0] = 0
 
     def tile_dmas(slot, t):
         return (
@@ -207,12 +285,14 @@ def _fused_kernel(q_ref, qscale_ref, docs_hbm, ids_hbm, scale_hbm,
         scores = _masked_scores(
             q_ref[...], docs_buf[cur], ids_buf[cur], scale_buf[cur],
             qscale_ref[...], int8_dot=int8_dot)            # (B, TILE_N)
-        _merge_tile_into_carry(scores, ids_buf[cur], carry_v, carry_i, k=k)
+        out_steps_ref[0] += _merge_tile_into_carry(
+            scores, ids_buf[cur], t * tile_n, pool, carry_v, carry_i, carry_p,
+            k=k)
         return 0
 
     jax.lax.fori_loop(0, tiles, step, 0)
-    out_vals_ref[...] = carry_v[...]
-    out_idx_ref[...] = carry_i[...]
+    _order_carry(carry_v[...], carry_i[...], carry_p[...], out_vals_ref,
+                 out_idx_ref, k=k)
 
 
 def _scan_cost(n: int, d: int, b: int, k: int, itemsize: int,
@@ -245,7 +325,9 @@ def knn_fused_topk(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array,
     ``int8_dot`` (with ``q_scale`` its (B,) f32 per-query multiplier);
     scale: (N,) f32 per-document score multipliers (None for an
     unquantized corpus).  Returns (scores (B, k) f32 descending, ids
-    (B, k) int32, -1 at -inf positions).
+    (B, k) int32, -1 at -inf positions, and the int32 count of merge steps
+    run over all tiles — at most ``tiles * min(k, tile_n)``).  Equal
+    scores rank by corpus position.
     """
     n, d = docs.shape
     b = queries.shape[0]
@@ -265,7 +347,7 @@ def knn_fused_topk(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array,
                                tiles=tiles, int8_dot=int8_dot)
     itemsize = jnp.dtype(docs.dtype).itemsize
     kp = lane_pad(k)
-    vals, idx = pl.pallas_call(
+    vals, idx, steps = pl.pallas_call(
         kernel,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),         # queries: resident
@@ -277,17 +359,21 @@ def knn_fused_topk(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array,
         out_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),         # merge steps
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, kp), jnp.float32),
             jax.ShapeDtypeStruct((b, kp), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, tile_n, d), docs.dtype),        # double tile buf
             pltpu.VMEM((2, 1, tile_n), jnp.int32),         # double id buf
             pltpu.VMEM((2, 1, tile_n), jnp.float32),       # double scale buf
+            pltpu.VMEM((b, tile_n), jnp.float32),          # tile's candidates
             pltpu.VMEM((b, kp), jnp.float32),              # running top-k vals
             pltpu.VMEM((b, kp), jnp.int32),                # running top-k ids
+            pltpu.VMEM((b, kp), jnp.int32),                # their positions
             pltpu.SemaphoreType.DMA((2,)),                 # docs DMA sems
             pltpu.SemaphoreType.DMA((2,)),                 # ids DMA sems
             pltpu.SemaphoreType.DMA((2,)),                 # scale DMA sems
@@ -296,7 +382,7 @@ def knn_fused_topk(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array,
         interpret=interpret,
         name="knn_fused_topk",
     )(queries, qscale_col, docs, ids_3d, scale_3d)
-    return vals[:, :k], idx[:, :k]
+    return vals[:, :k], idx[:, :k], steps[0]
 
 
 def _knn_kernel(q_ref, qscale_ref, docs_ref, ids_ref, scale_ref, out_vals_ref,

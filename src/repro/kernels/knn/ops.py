@@ -165,7 +165,7 @@ def knn_search(docs: jax.Array, doc_ids: jax.Array, queries: jax.Array, k: int,
     interp = dispatch.interpret_flag(be)
 
     if not two_stage:
-        vals, idx = knn_fused_topk(docs_p, ids_p, q_p, k, tile_n=tile_n,
+        vals, idx, _ = knn_fused_topk(docs_p, ids_p, q_p, k, tile_n=tile_n,
                                    interpret=interp, scale=scale_p,
                                    q_scale=qscale_p, int8_dot=use_i8)
         return vals[:b], idx[:b]

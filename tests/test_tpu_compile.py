@@ -2,8 +2,9 @@
 
 The chip is described, not attached: each case lowers and compiles one
 kernel entry point at the deployment's widths (769-d corpus rows and cache
-entries, 64-row waves, k_c = 1000) for one chip of a ``v5e:2x2`` topology
-and checks that the Mosaic kernel is in the compiled program.  A tiling or
+entries, 64-row waves, k_c = 1000; the scan also at the benchmark's
+k_c = 2000 with 8 and 32 rows) for one chip of a ``v5e:2x2`` topology and
+checks that the Mosaic kernel is in the compiled program.  A tiling or
 VMEM mistake that interpret mode cannot see fails here, with no chip time
 spent.  The topology is described inside a module fixture, which skips
 when this installation cannot describe it.
@@ -58,14 +59,19 @@ def _assert_kernel(fn, *args):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
-def test_knn_search_compiles(one_chip, dtype):
+@pytest.mark.parametrize("dtype,k_c,rows", [
+    pytest.param(jnp.float32, K_C, S, id="float32"),
+    pytest.param(jnp.int8, K_C, S, id="int8"),
+    pytest.param(jnp.int8, 2000, 8, id="int8-k2000-8"),   # the benchmark's
+    pytest.param(jnp.int8, 2000, 32, id="int8-k2000-32"),
+])
+def test_knn_search_compiles(one_chip, dtype, k_c, rows):
     _assert_kernel(
-        lambda d, i, s, q: knn_search(d, i, q, K_C, backend="compiled",
+        lambda d, i, s, q: knn_search(d, i, q, k_c, backend="compiled",
                                       scale=s),
         _shape(one_chip, (N, D), dtype), _shape(one_chip, (N,), jnp.int32),
         _shape(one_chip, (N,), jnp.float32),
-        _shape(one_chip, (S, D), jnp.float32))
+        _shape(one_chip, (rows, D), jnp.float32))
 
 
 def _state(one_chip, store):
