@@ -21,6 +21,7 @@ the batched-variant / kernel-dispatch contract.
 
 from __future__ import annotations
 
+import functools
 import threading
 
 import jax
@@ -70,11 +71,22 @@ __all__ = ["CacheState", "CacheConfig", "ProbeResult", "init_cache",
            "validate_state"]
 
 
-@jax.jit
+# The stacked state is written in place: each write donates the state it
+# replaces, so a write-back or a reset moves only the rows it writes
+# instead of copying the whole stack (the L1 state of a serving chip is
+# gigabytes, and a copy of it next to a wave's buffers can exhaust HBM).
+@functools.partial(jax.jit, donate_argnums=0)
 def _scatter_rows(state: CacheState, idx: jax.Array,
                   sub: CacheState) -> CacheState:
     return jax.tree_util.tree_map(
         lambda full, part: full.at[idx].set(part, mode="drop"), state, sub)
+
+
+@functools.partial(jax.jit, static_argnums=2, donate_argnums=0)
+def _reset_rows(state: CacheState, idx: jax.Array,
+                cfg: CacheConfig) -> CacheState:
+    return jax.tree_util.tree_map(
+        lambda full, one: full.at[idx].set(one), state, init_cache(cfg))
 
 
 class MetricCache:
@@ -149,7 +161,11 @@ class BatchedMetricCache:
     the serving path makes them from several threads (a session opens on
     its caller's thread while the scheduler's worker writes a wave back),
     so each holds one lock: without it one write is lost, and a session
-    is answered from its slot's previous occupant.
+    is answered from its slot's previous occupant.  Resets and write-backs
+    donate the state they replace (it is updated in place, and the
+    arrays of the old ``state`` are deleted), so a read that may run
+    beside them goes through ``read``, under the same lock; ``state``
+    itself is for callers that no other thread writes beside.
     """
 
     def __init__(self, cfg: CacheConfig, n_sessions: int):
@@ -166,27 +182,31 @@ class BatchedMetricCache:
                 self.state = init_batched_cache(self.cfg, self.n_sessions)
                 self.total_dropped = 0
             return
-        # write only the target rows (a fresh full stacked state per open
-        # would make opening S sessions O(S^2) in state traffic)
+        # write only the target rows, in place
         idx = jnp.asarray(sessions)
-        fresh = init_cache(self.cfg)
         with self._write:
-            self.state = jax.tree_util.tree_map(
-                lambda full, one: full.at[idx].set(one), self.state, fresh)
+            self.state = _reset_rows(self.state, idx, self.cfg)
+
+    def read(self, fn):
+        """``fn(state)``, dispatched under the state lock: what it returns
+        must be new arrays, not leaves of ``state``."""
+        with self._write:
+            return fn(self.state)
 
     @property
     def n_docs(self):
-        return jax.device_get(self.state.n_docs)
+        return jax.device_get(self.read(lambda s: s.n_docs + 0))
 
     @property
     def n_queries(self):
-        return jax.device_get(
-            jnp.minimum(self.state.n_queries, self.cfg.max_queries))
+        return jax.device_get(self.read(
+            lambda s: jnp.minimum(s.n_queries, self.cfg.max_queries)))
 
     def gather(self, sessions) -> CacheState:
         """Sub-state holding only the given session indices (a wave)."""
         idx = jnp.asarray(sessions)
-        return jax.tree_util.tree_map(lambda x: x[idx], self.state)
+        return self.read(
+            lambda st: jax.tree_util.tree_map(lambda x: x[idx], st))
 
     def scatter(self, sessions, sub: CacheState):
         """Write a wave's updated sub-state back into the stacked state:
@@ -199,8 +219,8 @@ class BatchedMetricCache:
 
     def probe(self, psi, epsilon=None, backend=None) -> ProbeResult:
         eps = self.cfg.epsilon if epsilon is None else epsilon
-        return probe_batched(self.state, psi, eps, backend=backend,
-                             max_queries=self.cfg.max_queries)
+        return self.read(lambda st: probe_batched(
+            st, psi, eps, backend=backend, max_queries=self.cfg.max_queries))
 
     def query(self, psi, k: int, backend=None):
         with self._write:

@@ -67,6 +67,10 @@ prefix invariant the insert positions rely on).
 
 Host-side bookkeeping (admission counts, the memo ring, claim stamps) is
 numpy; everything touching embeddings at scale is the shared kernel path.
+``h2d_bytes`` / ``d2h_bytes`` count the ``nbytes`` of every array the tier
+itself stages on the device or reads back (shard indices, expiry masks,
+admitted answers, ring positions, dropped counts), so a caller can add
+the tier's transfers to its own.
 """
 
 from __future__ import annotations
@@ -143,6 +147,8 @@ class SharedTier:
         self.n_memo_served = 0
         self.n_stale_served = 0      # memo serves under allow_stale outage
         self.total_dropped = 0
+        self.h2d_bytes = 0           # staged on the device (monotone)
+        self.d2h_bytes = 0           # read back from it (monotone)
 
     # ---------------------------------------------------------------- waves
 
@@ -157,9 +163,10 @@ class SharedTier:
             self._claim_alive,
             self.wave - self._claim_wave > self.ttl_waves)
         if stale.any():
+            mask = jnp.asarray(stale)
+            self.h2d_bytes += mask.nbytes
             self.state = self.state._replace(
-                q_radius=jnp.where(jnp.asarray(stale), -jnp.inf,
-                                   self.state.q_radius))
+                q_radius=jnp.where(mask, -jnp.inf, self.state.q_radius))
             self._claim_alive[stale] = False
 
     # -------------------------------------------------------------- routing
@@ -172,10 +179,12 @@ class SharedTier:
 
     def _gather(self, shards: np.ndarray) -> CacheState:
         idx = jnp.asarray(shards)
+        self.h2d_bytes += idx.nbytes
         return jax.tree_util.tree_map(lambda x: x[idx], self.state)
 
     def _scatter(self, shards: np.ndarray, sub: CacheState) -> None:
         idx = jnp.asarray(shards)
+        self.h2d_bytes += idx.nbytes
         self.state = jax.tree_util.tree_map(
             lambda full, part: full.at[idx].set(part), self.state, sub)
 
@@ -306,14 +315,18 @@ class SharedTier:
         for shard, p, r, e, i in answers:
             psi[shard], radius[shard], emb[shard], ids[shard] = p, r, e, i
             do[shard] = True
-        slots = np.asarray(self.state.n_queries) % self.cfg.max_queries
+        n_queries = np.asarray(self.state.n_queries)
+        slots = n_queries % self.cfg.max_queries
         self.state, dropped = insert_batched(
             self.state, self.cfg, psi, radius, emb, ids, do=do,
             backend=backend or self.backend)
+        dropped = np.asarray(dropped)
+        self.h2d_bytes += sum(x.nbytes for x in (psi, radius, emb, ids, do))
+        self.d2h_bytes += n_queries.nbytes + dropped.nbytes
         shards = np.nonzero(do)[0]
         self._claim_wave[shards, slots[shards]] = self.wave
         self._claim_alive[shards, slots[shards]] = True
-        self.total_dropped += int(np.asarray(dropped).sum())
+        self.total_dropped += int(dropped.sum())
 
     # ------------------------------------------------------------ result memo
 

@@ -83,6 +83,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+from collections import Counter
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -140,6 +141,7 @@ class WaveState:
     outage: Optional[BaseException] = None
     probe_s: float = 0.0
     backend_s: float = 0.0
+    l2_s: float = 0.0                # inside the L2 tier: probe and flush
     h2d_bytes: int = 0               # bytes the wave staged on the device
     d2h_bytes: int = 0               # ... and read back, so far
 
@@ -236,8 +238,8 @@ class BatchedEngine:
         crashing the wave.  Returns the reset slot indices.  Runs
         automatically every ``validate_every`` waves when that knob is
         set; callable directly after any suspected corruption."""
-        ok, _problems = validate_state(self.cache.state, self.cache.cfg,
-                                       n_corpus=len(self.doc_embeddings))
+        ok, _problems = self.cache.read(lambda st: validate_state(
+            st, self.cache.cfg, n_corpus=len(self.doc_embeddings)))
         bad = np.nonzero(~np.asarray(ok))[0]
         if bad.size:
             self.cache.reset(bad.tolist())
@@ -290,8 +292,12 @@ class BatchedEngine:
                   bucket=bucket) as sp:
             ws = self._probe(sids, queries, admitted_at, t_start, bucket)
             sp.set_metadata(h2d_bytes=ws.h2d_bytes, d2h_bytes=ws.d2h_bytes)
-        self.telemetry.count(h2d_bytes=ws.h2d_bytes, d2h_bytes=ws.d2h_bytes,
-                             wave_rows=wave, bucket_rows=bucket)
+            took = Counter(ws.tier[:wave])
+            self.telemetry.count(
+                h2d_bytes=ws.h2d_bytes, d2h_bytes=ws.d2h_bytes,
+                wave_rows=wave, bucket_rows=bucket,
+                l1_hit_rows=took["l1"], l2_hit_rows=took["l2"],
+                l2_reuse_rows=took["l2_reuse"], backend_rows=took["backend"])
         ws.probe_s = time.perf_counter() - t_start
         return ws
 
@@ -343,13 +349,19 @@ class BatchedEngine:
         rad = np.zeros((bucket,), np.float32)
         rec_np = np.zeros((bucket,), bool)
 
+        l2_s, l2_h2d, l2_d2h = 0.0, 0, 0
         if self.shared is not None:
+            t_l2 = time.perf_counter()
             with span("engine.probe.l2"), self._shared_lock:
+                moved = (self.shared.h2d_bytes, self.shared.d2h_bytes)
                 self.shared.tick()
                 if need.any():
-                    need = self._probe_shared(pad_sids, psi, psi_np, need,
-                                              reuse, l2hit, new_ids,
-                                              new_emb, rad, rec_np)
+                    need, l2_d2h = self._probe_shared(
+                        pad_sids, psi, psi_np, need, reuse, l2hit, new_ids,
+                        new_emb, rad, rec_np)
+                l2_h2d = self.shared.h2d_bytes - moved[0]
+                l2_d2h += self.shared.d2h_bytes - moved[1]
+            l2_s = time.perf_counter() - t_l2
             tier[reuse] = "l2_reuse"
             tier[l2hit] = "l2"
 
@@ -362,58 +374,80 @@ class BatchedEngine:
             failed=np.zeros((bucket,), bool),
             stale=np.zeros((bucket,), bool),
             admitted_at=admitted, t_start=t_start, wave_id=self.wave_id,
-            # the staged queries and slot row; the probe's reads
-            h2d_bytes=q.nbytes + pad_sids.nbytes,
-            d2h_bytes=n_queries.nbytes + hit.nbytes + psi_np.nbytes)
+            l2_s=l2_s,
+            # the staged queries and slot row; the probe's reads; the L2
+            # tier's own transfers and the reads of its answers
+            h2d_bytes=q.nbytes + pad_sids.nbytes + l2_h2d,
+            d2h_bytes=n_queries.nbytes + hit.nbytes + psi_np.nbytes + l2_d2h)
 
     def _probe_shared(self, pad_sids, psi, psi_np, need, reuse, l2hit,
-                      new_ids, new_emb, rad, rec_np) -> np.ndarray:
+                      new_ids, new_emb, rad, rec_np) -> tuple:
         """Tiered lookups for L1 misses (caller holds the shared lock).
-        Returns the residual miss mask after memo reuse and L2 hits."""
+        Returns the residual miss mask after memo reuse and L2 hits, and
+        the bytes read back of the tier's answers."""
         l2 = self.shared
+        read = 0
+        width = {}                   # row -> answer columns it takes
+        claims = {}                  # memo row -> its Eq. 3 claim
         # L2a — semantic result reuse (host-side memo; no launch): a
         # near-duplicate query from ANOTHER session reuses its full
         # k_c result set, and records the triangle-corrected Eq. 3
         # claim r_a - delta(psi_a, psi) when it still clears epsilon
-        for i in np.nonzero(need)[0]:
-            m = l2.memo_lookup(self._token(pad_sids[i]), psi_np[i])
-            if m is None:
-                continue
-            m_ids, _m_scores, claim = m
-            reuse[i] = True
-            n = min(self.k_c, m_ids.shape[0])
-            new_ids[i, :n] = m_ids[:n]
-            new_emb[i, :n] = self.doc_embeddings[
-                np.maximum(m_ids[:n], 0)]
-            if claim >= self.epsilon:
-                rad[i] = claim
-                rec_np[i] = True
-            # the reusing session is a distinct retriever of these
-            # docs — it counts toward the >= 2-sessions admission bar
-            l2.offer(self._token(pad_sids[i]), psi_np[i], claim,
-                     new_emb[i], new_ids[i])
+        rows = np.nonzero(need)[0]
+        with span("l2.memo", rows=len(rows)) as sp:
+            for i in rows:
+                m = l2.memo_lookup(self._token(pad_sids[i]), psi_np[i])
+                if m is None:
+                    continue
+                m_ids, _m_scores, claim = m
+                reuse[i] = True
+                claims[i] = claim
+                width[i] = min(self.k_c, m_ids.shape[0])
+                new_ids[i, :width[i]] = m_ids[:width[i]]
+                if claim >= self.epsilon:
+                    rad[i] = claim
+                    rec_np[i] = True
+            sp.set_metadata(hits=len(claims))
         rem = np.logical_and(need, ~reuse)
         if rem.any():
             # L2b — launch 2: the SAME LowQuality probe kernel over the
             # gathered shard rows of the shared tier (whole bucket, one
             # jitted shape; results masked to the residual misses)
-            shards = l2.route(psi_np)
-            l2pr = l2.probe_rows(psi, shards, backend=self.backend)
-            l2hit[:] = np.logical_and(np.asarray(l2pr.hit), rem)
-            if l2hit.any():
-                # covered by a shared claim: answer from the shard's
-                # cached docs (one fused wave-query launch, only when
-                # L2 actually serves someone)
-                (_s2, _d2, i2, _sl2) = l2.query_rows(
-                    psi, shards, self.k, backend=self.backend)
-                i2_np = np.asarray(i2)
-                for i in np.nonzero(l2hit)[0]:
-                    row = i2_np[i][i2_np[i] >= 0]
-                    n = min(self.k_c, row.shape[0])
-                    new_ids[i, :n] = row[:n]
-                    new_emb[i, :n] = self.doc_embeddings[row[:n]]
-            return np.logical_and(rem, ~l2hit)
-        return rem
+            with span("l2.route"):
+                shards = l2.route(psi_np)
+            with span("l2.probe", rows=int(rem.sum()),
+                      shards=len(np.unique(shards[rem]))) as sp:
+                moved = (l2.h2d_bytes, l2.d2h_bytes)
+                hit = np.asarray(
+                    l2.probe_rows(psi, shards, backend=self.backend).hit)
+                read += hit.nbytes
+                l2hit[:] = np.logical_and(hit, rem)
+                if l2hit.any():
+                    # covered by a shared claim: answer from the shard's
+                    # cached docs (one fused wave-query launch, only when
+                    # L2 actually serves someone)
+                    (_s2, _d2, i2, _sl2) = l2.query_rows(
+                        psi, shards, self.k, backend=self.backend)
+                    i2_np = np.asarray(i2)
+                    read += i2_np.nbytes
+                sp.set_metadata(h2d_bytes=l2.h2d_bytes - moved[0],
+                                d2h_bytes=l2.d2h_bytes - moved[1] + read)
+            for i in np.nonzero(l2hit)[0]:
+                row = i2_np[i][i2_np[i] >= 0]
+                width[i] = min(self.k_c, row.shape[0])
+                new_ids[i, :width[i]] = row[:width[i]]
+        if width:
+            # the served answers' rows, from the host copy of the corpus
+            with span("l2.rows", rows=len(width)):
+                for i, n in width.items():
+                    new_emb[i, :n] = self.doc_embeddings[
+                        np.maximum(new_ids[i, :n], 0)]
+        # the reusing session is a distinct retriever of these docs — it
+        # counts toward the >= 2-sessions admission bar
+        for i, claim in claims.items():
+            l2.offer(self._token(pad_sids[i]), psi_np[i], claim,
+                     new_emb[i], new_ids[i])
+        return np.logical_and(rem, ~l2hit), read
 
     # ----------------------------------------------------- backend phase
     def backend_wave(self, ws: WaveState) -> WaveState:
@@ -629,8 +663,18 @@ class BatchedEngine:
         if self.shared is not None:
             # end-of-wave: promote the wave's admitted answers into their
             # shards (deferred so admission never adds launches mid-wave)
-            with span("engine.fill.l2_flush"), self._shared_lock:
-                self.shared.flush_admissions(backend=self.backend)
+            t_l2 = time.perf_counter()
+            with span("engine.fill.l2_flush"), self._shared_lock, \
+                    span("l2.flush") as sp:
+                moved = (self.shared.h2d_bytes, self.shared.d2h_bytes)
+                admitted = self.shared.flush_admissions(backend=self.backend)
+                h2d = self.shared.h2d_bytes - moved[0]
+                d2h = self.shared.d2h_bytes - moved[1]
+                sp.set_metadata(admitted=admitted, h2d_bytes=h2d,
+                                d2h_bytes=d2h)
+            ws.h2d_bytes += h2d
+            ws.d2h_bytes += d2h
+            ws.l2_s += time.perf_counter() - t_l2
 
         resolved = time.perf_counter()
         insert_s = resolved - t0
@@ -659,8 +703,9 @@ class BatchedEngine:
                 spans = TurnSpans(
                     queue_wait_s=max(ws.t_start - admitted, 0.0),
                     probe_s=ws.probe_s, backend_s=ws.backend_s,
-                    insert_s=insert_s, total_s=resolved - admitted,
-                    tier=row_tier, wave=ws.wave_id)
+                    insert_s=insert_s, l2_s=ws.l2_s,
+                    total_s=resolved - admitted, tier=row_tier,
+                    wave=ws.wave_id)
                 # a degraded wave degrades its backend-tier rows AND any
                 # row served stale-while-error (fresh tier hits stay
                 # first-class)

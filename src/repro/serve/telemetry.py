@@ -9,9 +9,10 @@ open-loop harness share:
   * ``TurnSpans`` — one turn's latency decomposition: queue wait
     (admission -> wave start), probe (L1/L2 cache launches), backend
     (router round-trip over the miss subset), insert (fused insert+query
-    close), and the admission-to-resolution total.  Spans other than
-    queue wait are wave-level (every turn of a wave shares them); the
-    queue wait and total are strictly per turn.
+    close), the part of probe and insert spent in the shared L2 tier, and
+    the admission-to-resolution total.  Spans other than queue wait are
+    wave-level (every turn of a wave shares them); the queue wait and
+    total are strictly per turn.
   * ``RingPercentiles`` — a fixed-capacity ring buffer with nearest-rank
     percentile estimates over the most recent window.  O(1) insertion on
     the serving path; sorting is deferred to ``percentile()``/
@@ -23,9 +24,10 @@ open-loop harness share:
   * ``ServeTelemetry`` — the aggregate the engine/scheduler write into:
     one ring per span kind, one ring of totals per serving tier
     (l1 / l2 / l2_reuse / backend), wave-size and wave-service histories,
-    monotone counters (host<->device bytes, wave and bucket rows, eligible
-    rows, gc pauses), and a ``summary()`` that flattens to the p50/p95/p99
-    columns ``BENCH_serve.json`` commits and ``check_regression.py`` gates.
+    monotone counters (host<->device bytes, wave and bucket rows, rows
+    by serving tier, eligible rows, gc pauses), and a ``summary()`` that
+    flattens to the p50/p95/p99 columns ``BENCH_serve.json`` commits and
+    ``check_regression.py`` gates.
   * ``span(name, **args)`` — a ``jax.profiler.TraceAnnotation`` named
     ``repro.<name>``: a host span on the profiler's own trace, on the clock
     of the device's ops, with ``args`` as its event stats.  The serving
@@ -102,6 +104,7 @@ class TurnSpans:
     backend_s: float = 0.0
     insert_s: float = 0.0
     total_s: float = 0.0
+    l2_s: float = 0.0      # inside the L2 tier: its probe and its flush
     tier: str = "backend"
     wave: int = 0          # the engine's wave id: its spans' ``wave`` arg
 
@@ -230,18 +233,24 @@ class ServeTelemetry:
 
     Counters (``COUNTERS``, monotone ints, always on): ``h2d_bytes`` /
     ``d2h_bytes`` — the ``nbytes`` of every array a wave stages on or
-    reads back from the device (engine phases and device shards);
+    reads back from the device (engine phases, device shards and the
+    shared L2 tier);
     ``wave_rows`` / ``bucket_rows`` — real and padded rows of the waves
-    probed; ``eligible_rows`` — turns that could have joined each wave
-    the scheduler took; ``gc_pauses`` — garbage collections since this
-    object was made.  The spans carry the same numbers per wave.
+    probed; ``l1_hit_rows`` / ``l2_hit_rows`` / ``l2_reuse_rows`` /
+    ``backend_rows`` — the same real rows by the tier that took them at
+    the probe (L1 hit, L2 shard hit, L2 memo reuse, sent on to the back
+    end), summing to ``wave_rows``; ``eligible_rows`` — turns that could
+    have joined each wave the scheduler took; ``gc_pauses`` — garbage
+    collections since this object was made.  The spans carry the same
+    numbers per wave.
     """
 
     COUNTERS = ("h2d_bytes", "d2h_bytes", "wave_rows", "bucket_rows",
+                "l1_hit_rows", "l2_hit_rows", "l2_reuse_rows", "backend_rows",
                 "eligible_rows", "gc_pauses")
 
     SPAN_KEYS = ("queue_wait_s", "probe_s", "backend_s", "insert_s",
-                 "total_s")
+                 "l2_s", "total_s")
 
     def __init__(self, capacity: int = 4096, ewma_horizon_s: float = 1.0):
         self.spans = {k: RingPercentiles(capacity) for k in self.SPAN_KEYS}
@@ -259,6 +268,8 @@ class ServeTelemetry:
         self._fault_lock = threading.Lock()
         self.h2d_bytes = self.d2h_bytes = 0
         self.wave_rows = self.bucket_rows = self.eligible_rows = 0
+        self.l1_hit_rows = self.l2_hit_rows = self.l2_reuse_rows = 0
+        self.backend_rows = 0
         self._count_lock = threading.Lock()
         self._gc0 = _watch_gc()
 

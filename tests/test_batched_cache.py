@@ -137,7 +137,8 @@ def test_reset_and_scatter_from_two_threads_lose_no_write():
     """Sessions open (``reset``) on callers' threads while the serving
     worker writes waves back (``scatter``) into the same stacked state.
     Each is a read-modify-write of the whole state: a reset that lands
-    inside a write-back must not be undone by it."""
+    inside a write-back must not be undone by it.  Both update the state
+    in place, so the threads read it through ``gather``, under its lock."""
     import sys
     import threading
     import time
@@ -155,13 +156,13 @@ def test_reset_and_scatter_from_two_threads_lose_no_write():
     def writer(stop):             # the worker: waves into slot 0 only
         while time.perf_counter() < stop:
             cache.scatter(np.array([0], np.int32), clean)
-            jax.block_until_ready(cache.state)
+            jax.block_until_ready(cache.gather([0]))
 
     def opener(slot, stop):       # a caller: dirty its slot, then reset
         while time.perf_counter() < stop:
             cache.scatter(np.array([slot], np.int32), dirty)
             cache.reset([slot])
-            if int(np.asarray(cache.state.doc_ids[slot, 0])) == 7:
+            if int(np.asarray(cache.gather([slot]).doc_ids[0, 0])) == 7:
                 lost.append(slot)
 
     was = sys.getswitchinterval()
